@@ -62,7 +62,9 @@ async def amain(args) -> int:
     failures = []
     for i, r in enumerate(ok):
         want = list(range(args.max_tokens))
-        if r.token_indices != want:
+        if r.error is not None:
+            failures.append(f"stream {i}: ended early with {r.error}")
+        elif r.token_indices != want:
             failures.append(
                 f"stream {i}: token_index {r.token_indices} != {want} "
                 f"(duplicated, dropped or out-of-order chunks)")

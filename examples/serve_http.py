@@ -1,7 +1,15 @@
-"""Boot the OpenAI-compatible HTTP front end on the tiny model.
+"""Boot the OpenAI-compatible HTTP front end.
 
     PYTHONPATH=src python examples/serve_http.py [--port 8008]
         [--max-batch 3] [--paged] [--n-pages 48] [--max-queue 32]
+        [--model tiny|<registry name>] [--layers N] [--max-len 96]
+
+The default is the tiny model.  ``--model llama3-8b --layers 4`` serves
+the registered Llama-3-8B at its published widths with the depth cut to 4
+layers (random weights from seed 0); such a model wants a TPU, a larger
+``--max-len``/``--n-pages`` and a larger ``--max-tokens-cap``.  JAX's
+compile cache goes to ``$JAX_COMPILATION_CACHE_DIR``, else ``.jax_cache/``
+in the checkout.
 
 Then drive it with curl:
 
@@ -33,34 +41,18 @@ import json
 import os
 import tempfile
 
-from repro.core.llama_graph import LlamaSpec, init_llama_params
+from repro.core.llama_graph import init_llama_params
 from repro.obs import MetricsRegistry, TraceRecorder
-from repro.serving.engine import RelationalEngine
-from repro.serving.kvcache import PagedKVCache, PagedKVConfig
-from repro.serving.server import AsyncLLMServer, ServerConfig
+from repro.serving.boot import build_server, enable_compile_cache, model_spec
+from repro.serving.server import ServerConfig
 
 
-def build_server(args, metrics, tracer, disk_dir=None) -> AsyncLLMServer:
-    spec = LlamaSpec(vocab=512, d_model=128, n_layers=3, n_heads=4, n_kv=2,
-                     d_ff=256, rope_theta=10000.0)
+def make_server(args, metrics, tracer, disk_dir=None):
+    spec = model_spec(args.model, args.layers)
     params = init_llama_params(spec, seed=0)
-    if args.paged:
-        model_bytes = sum(a.size * a.dtype.itemsize for a in params.values())
-        eng = RelationalEngine(spec, params, chunk_size=64,
-                               residency="paged",
-                               budget_bytes=model_bytes // 4,
-                               disk_dir=disk_dir, max_len=96,
-                               metrics=metrics, tracer=tracer)
-    else:
-        eng = RelationalEngine(spec, params, chunk_size=64, max_len=96,
-                               metrics=metrics, tracer=tracer)
-    # a page pool sized below max_batch's worst case keeps the preemption
-    # path honest under load (the scheduler resumes, never replays)
-    kvcfg = PagedKVConfig(n_layers=spec.n_layers, n_kv=spec.n_kv,
-                          head_dim=spec.head_dim, page_size=8,
-                          n_pages=args.n_pages, max_pages_per_seq=12)
-    kv = PagedKVCache(kvcfg, max_seqs=max(8, args.max_batch))
     cfg = ServerConfig(host=args.host, port=args.port,
+                       model_id=("transql-tiny" if args.model == "tiny"
+                                 else args.model),
                        max_batch=args.max_batch,
                        max_queue_depth=args.max_queue,
                        max_tokens_cap=args.max_tokens_cap,
@@ -71,7 +63,9 @@ def build_server(args, metrics, tracer, disk_dir=None) -> AsyncLLMServer:
                        flight_capacity=args.flight_capacity,
                        drift_every=args.drift_every,
                        drift_threshold=args.drift_threshold)
-    return AsyncLLMServer(eng, kv, cfg, metrics=metrics, tracer=tracer)
+    return build_server(spec, params, cfg, max_len=args.max_len,
+                        n_pages=args.n_pages, paged=args.paged,
+                        disk_dir=disk_dir, metrics=metrics, tracer=tracer)
 
 
 def dump_artifacts(server, metrics, tracer, out: str) -> None:
@@ -101,7 +95,7 @@ async def amain(args) -> None:
     with contextlib.ExitStack() as stack:
         disk = (stack.enter_context(tempfile.TemporaryDirectory())
                 if args.paged else None)
-        server = build_server(args, metrics, tracer, disk_dir=disk)
+        server = make_server(args, metrics, tracer, disk_dir=disk)
         await server.start()
         print(f"serving on http://{server.cfg.host}:{server.port} "
               f"(max_batch={server.batcher.max_batch}, "
@@ -124,6 +118,14 @@ def main():
         formatter_class=argparse.RawDescriptionHelpFormatter)
     ap.add_argument("--host", default="127.0.0.1")
     ap.add_argument("--port", type=int, default=8008)
+    ap.add_argument("--model", default="tiny",
+                    help="'tiny' or a registered dense Llama config "
+                         "(e.g. llama3-8b)")
+    ap.add_argument("--layers", type=int, default=None,
+                    help="cut a registered model's depth to this many "
+                         "layers (widths stay as published)")
+    ap.add_argument("--max-len", type=int, default=96,
+                    help="context cap: prompt + generated tokens")
     ap.add_argument("--max-batch", type=int, default=3)
     ap.add_argument("--max-queue", type=int, default=32)
     ap.add_argument("--max-tokens-cap", type=int, default=64)
@@ -140,6 +142,7 @@ def main():
     ap.add_argument("--drift-threshold", type=float, default=0.5,
                     help="RMS relative drift that triggers a re-plan")
     args = ap.parse_args()
+    enable_compile_cache()
     try:
         asyncio.run(amain(args))
     except KeyboardInterrupt:

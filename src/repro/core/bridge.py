@@ -3,18 +3,49 @@ production model stack's parameter tree (dense Llama family only).
 
 Used by the equivalence tests and the quickstart example to prove the two
 execution paths (relational pipelines vs direct JAX) implement the same
-model, weight-for-weight.
+model, weight-for-weight, and by the serving entry points to build the
+relational path from a registered config.
 """
 
 from __future__ import annotations
 
-from typing import Dict
+import dataclasses
+from typing import Dict, Optional
 
 import jax.numpy as jnp
 import numpy as np
 
 from repro.configs.base import ModelConfig
 from repro.core.llama_graph import LlamaSpec
+
+
+def config_to_spec(cfg: ModelConfig, n_layers: Optional[int] = None
+                   ) -> LlamaSpec:
+    """Registered ``ModelConfig`` → the relational path's ``LlamaSpec``
+    (the inverse of :func:`spec_to_config`).  ``n_layers`` cuts the depth;
+    every width stays as registered.  Raises ``ValueError`` for a config
+    outside the one graph family the relational compiler builds."""
+    plain = ModelConfig(name=cfg.name, family="dense", n_layers=cfg.n_layers,
+                        d_model=cfg.d_model, n_heads=cfg.n_heads,
+                        n_kv=cfg.n_kv, d_ff=cfg.d_ff, vocab=cfg.vocab,
+                        rope_theta=cfg.rope_theta, eps=cfg.eps)
+    # every architecture field must be the plain dense GQA decoder's;
+    # numerics/execution fields (dtype, remat, ...) do not shape the graph
+    arch = [f.name for f in dataclasses.fields(ModelConfig)
+            if f.name not in ("name", "scan_unroll", "dtype", "param_dtype",
+                              "remat", "ce_impl", "moe_impl", "max_positions",
+                              "capacity_factor")]
+    differ = [f for f in arch if getattr(cfg, f) != getattr(plain, f)]
+    if differ:
+        raise ValueError(f"{cfg.name}: the relational path builds only the "
+                         f"dense GQA Llama family; {differ} differ from it")
+    n = cfg.n_layers if n_layers is None else n_layers
+    if not 1 <= n <= cfg.n_layers:
+        raise ValueError(f"{cfg.name}: n_layers {n} outside [1, "
+                         f"{cfg.n_layers}]")
+    return LlamaSpec(vocab=cfg.vocab, d_model=cfg.d_model, n_layers=n,
+                     n_heads=cfg.n_heads, n_kv=cfg.n_kv, d_ff=cfg.d_ff,
+                     rope_theta=cfg.rope_theta, eps=cfg.eps)
 
 
 def spec_to_config(spec: LlamaSpec, dtype: str = "float32") -> ModelConfig:

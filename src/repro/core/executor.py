@@ -39,6 +39,13 @@ from repro.core.relational import (
 
 NEG_INF = -1e30
 
+# Precision of every contraction the executor emits.  The tables are f32
+# and the model they encode is the f32 one the tests check; a TPU would
+# otherwise contract f32 operands at DEFAULT precision (bf16 passes) and
+# serve a different function from the one checked on the CPU.  A payload
+# in another dtype brings its own precision and tolerance.
+CONTRACT_PRECISION = jax.lax.Precision.HIGHEST
+
 
 @dataclasses.dataclass
 class DenseTable:
@@ -90,18 +97,41 @@ def col_table_from_dense(arr, col_chunk: int, d_key: str = "d",
     the *output* dimension (planner ROW2COL physical layout).  Non-divisor
     chunk sizes zero-pad the output tail (the planner itself only picks
     divisors, but stored tables follow the §2.1 padding convention)."""
-    arr = jnp.asarray(arr)
-    m, n = arr.shape
-    n_chunks = max(1, -(-m // col_chunk))
-    pad = n_chunks * col_chunk - m
-    if pad:
-        arr = jnp.pad(arr, ((0, pad), (0, 0)))
-    data = arr.T.reshape(n, n_chunks, col_chunk)
+    data = _col_chunk_data(jnp.asarray(arr), col_chunk)
+    n, n_chunks, _ = data.shape
     return DenseTable(
         keys=((d_key, n), (chunk_key, n_chunks)),
         cols={vec_col: data},
         col_types={vec_col: ra.VEC(col_chunk)},
     )
+
+
+# The layout conversions run as one compiled program each: run op by op,
+# the reshape, pad and transpose would each hold a full copy of the table
+# at once (three lm_head-sized buffers at Llama-3-8B widths).
+@partial(jax.jit, static_argnums=1)
+def _col_chunk_data(arr, col_chunk: int):
+    """``[m, …]`` → ``[n, ⌈m/cs'⌉, cs']``, ``n`` the trailing size."""
+    arr = arr.reshape(arr.shape[0], -1)
+    m, n = arr.shape
+    n_chunks = max(1, -(-m // col_chunk))
+    pad = n_chunks * col_chunk - m
+    if pad:
+        arr = jnp.pad(arr, ((0, pad), (0, 0)))
+    return arr.T.reshape(n, n_chunks, col_chunk)
+
+
+@partial(jax.jit, static_argnums=1)
+def _colh_chunk_data(arr, col_chunk: int):
+    """``[H, dh, …]`` → ``[H, n, ⌈dh/cs'⌉, cs']``, ``n`` the trailing
+    size."""
+    arr = arr.reshape(arr.shape[0], arr.shape[1], -1)
+    H, dh, n = arr.shape
+    n_chunks = max(1, -(-dh // col_chunk))
+    pad = n_chunks * col_chunk - dh
+    if pad:
+        arr = jnp.pad(arr, ((0, 0), (0, pad), (0, 0)))
+    return arr.transpose(0, 2, 1).reshape(H, n, n_chunks, col_chunk)
 
 
 def transpose_chunked_table(table: DenseTable, col_chunk: int,
@@ -113,12 +143,11 @@ def transpose_chunked_table(table: DenseTable, col_chunk: int,
     SQL side is ``LayoutPlan.conversion_sql``)."""
     if len(table.keys) != 2 or len(table.cols) != 1:
         raise ValueError(f"not a 2-key chunked weight table: {table.keys}")
-    (jname, m), (cname, nch) = table.keys
     vec_col, arr = next(iter(table.cols.items()))
     if not is_vec(table.col_types[vec_col]):
         raise ValueError(f"column {vec_col} is not a vector column")
-    dense = arr.reshape(m, nch * arr.shape[-1])
-    return col_table_from_dense(dense, col_chunk, d_key=d_key,
+    # [m, nch, cs] flattens to [m, nch·cs] inside the conversion
+    return col_table_from_dense(arr, col_chunk, d_key=d_key,
                                 chunk_key=chunk_key, vec_col=vec_col)
 
 
@@ -131,13 +160,8 @@ def colh_table_from_dense(arr, col_chunk: int, head_key: str = "h",
     keys ``(h ∈ [H), d ∈ [n), c ∈ [⌈dh/cs'⌉))``, data
     ``[H, n, ⌈dh/cs'⌉, cs']`` (non-divisor sizes zero-pad the tail).
     """
-    arr = jnp.asarray(arr)
-    H, dh, n = arr.shape
-    n_chunks = max(1, -(-dh // col_chunk))
-    pad = n_chunks * col_chunk - dh
-    if pad:
-        arr = jnp.pad(arr, ((0, 0), (0, pad), (0, 0)))
-    data = arr.transpose(0, 2, 1).reshape(H, n, n_chunks, col_chunk)
+    data = _colh_chunk_data(jnp.asarray(arr), col_chunk)
+    H, n, n_chunks, _ = data.shape
     return DenseTable(
         keys=((head_key, H), (d_key, n), (chunk_key, n_chunks)),
         cols={vec_col: data},
@@ -154,12 +178,12 @@ def transpose_head_chunked_table(table: DenseTable, col_chunk: int,
     head-blocked ROW2COL conversion."""
     if len(table.keys) != 3 or len(table.cols) != 1:
         raise ValueError(f"not a 3-key per-head weight table: {table.keys}")
-    (hname, H), (rname, dh), (cname, nch) = table.keys
+    hname = table.keys[0][0]
     vec_col, arr = next(iter(table.cols.items()))
     if not is_vec(table.col_types[vec_col]):
         raise ValueError(f"column {vec_col} is not a vector column")
-    dense = arr.reshape(H, dh, nch * arr.shape[-1])
-    return colh_table_from_dense(dense, col_chunk, head_key=hname,
+    # [H, dh, nch, cs] flattens to [H, dh, nch·cs] inside the conversion
+    return colh_table_from_dense(arr, col_chunk, head_key=hname,
                                  d_key=d_key, chunk_key=chunk_key,
                                  vec_col=vec_col)
 
@@ -365,6 +389,8 @@ def _eval_expr(expr: Expr, table: DenseTable) -> Tuple[jnp.ndarray, bool]:
         return fn(lv, rv), lvec or rvec
     if isinstance(expr, Call):
         if expr.fn == "dot":
+            # an elementwise product and a sum, both exact f32 ops on every
+            # backend: no matmul unit, so no matmul precision applies
             a, _ = _eval_expr(expr.args[0], table)
             b, _ = _eval_expr(expr.args[1], table)
             return jnp.sum(a * b, axis=-1), False
@@ -616,7 +642,8 @@ def _try_fused_join_agg(node: GroupAgg, env, memo, scalars=None,
     out_vec = (lvec or rvec) and not contract_payload
     o_sub = "".join(letter(k) for k in out_names) + (
         letter("__w") if out_vec else "")
-    res = jnp.einsum(f"{l_sub},{r_sub}->{o_sub}", larr, rarr)
+    res = jnp.einsum(f"{l_sub},{r_sub}->{o_sub}", larr, rarr,
+                     precision=CONTRACT_PRECISION)
     if scale_const is not None:
         res = res * scale_const
 

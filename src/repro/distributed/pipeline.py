@@ -20,7 +20,6 @@ from typing import Callable
 import jax
 import jax.numpy as jnp
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
-from jax.experimental.shard_map import shard_map
 
 
 def pipeline_apply(
@@ -83,6 +82,6 @@ def pipeline_apply(
             jnp.where(stage == n_stages - 1, out, jnp.zeros_like(out)),
             stage_axis)
 
-    fn = shard_map(stage_fn, mesh=mesh, in_specs=(pspec, xspec),
-                   out_specs=xspec, check_rep=False)
+    fn = jax.shard_map(stage_fn, mesh=mesh, in_specs=(pspec, xspec),
+                       out_specs=xspec, check_vma=False)
     return fn(stacked_params, x)

@@ -1,32 +1,22 @@
 """Public jit'd wrappers for the Pallas kernels.
 
-On CPU (this container) kernels run in ``interpret=True`` mode — the
-kernel body executes in Python for correctness validation; on TPU they
-compile to Mosaic.  ``use_kernels(False)`` falls back to the jnp oracles
-(used by the models' XLA path and as a safety valve).
+The kernels compile to Mosaic for a TPU.  ``interpret=True`` runs the
+kernel body in Python instead (correctness checks on a CPU backend); it is
+never chosen for the caller, so a kernel that cannot compile fails loudly
+instead of silently running interpreted.
 """
 
 from __future__ import annotations
 
-import functools
-
-import jax
 import jax.numpy as jnp
 
-from repro.kernels import ref
 from repro.kernels.chunked_matmul import chunked_matmul as _cm_kernel
 from repro.kernels.flash_attention import flash_attention as _fa_kernel
 from repro.kernels.paged_attention import paged_attention as _pa_kernel
 
 
-def _interpret_default() -> bool:
-    return jax.default_backend() != "tpu"
-
-
-def chunked_matmul(x, w, *, bm=128, bn=128, bk=128, interpret=None):
+def chunked_matmul(x, w, *, bm=128, bn=128, bk=128, interpret=False):
     """C = X Wᵀ via the chunked relational GEMM kernel (pads to tiles)."""
-    if interpret is None:
-        interpret = _interpret_default()
     M, K = x.shape
     N = w.shape[0]
     bm_, bn_, bk_ = min(bm, M), min(bn, N), min(bk, K)
@@ -37,22 +27,14 @@ def chunked_matmul(x, w, *, bm=128, bn=128, bk=128, interpret=None):
     return out[:M, :N]
 
 
-def flash_attention(q, k, v, *, causal=True, bq=128, bk=128, interpret=None):
-    if interpret is None:
-        interpret = _interpret_default()
+def flash_attention(q, k, v, *, causal=True, bq=128, bk=128,
+                    interpret=False):
     return _fa_kernel(q, k, v, causal=causal, bq=bq, bk=bk,
                       interpret=interpret)
 
 
 def paged_attention(q, k_pool, v_pool, page_table, lengths, *,
-                    interpret=None):
-    if interpret is None:
-        interpret = _interpret_default()
+                    interpret=False):
     return _pa_kernel(q, k_pool, v_pool, page_table, lengths,
                       interpret=interpret)
 
-
-# jnp oracles re-exported for the fallback path
-ref_chunked_matmul = ref.chunked_matmul
-ref_flash_attention = ref.flash_attention
-ref_paged_attention = ref.paged_attention

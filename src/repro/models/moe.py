@@ -117,7 +117,6 @@ def moe_apply_ep_local(p: Dict, x: jnp.ndarray, cfg: ModelConfig
     the same wire cost as a TP MLP all-reduce, instead of gathering the
     full expert buffer.
     """
-    from jax.experimental.shard_map import shard_map
     from jax.sharding import PartitionSpec as P
     from repro.distributed.sharding import current_mesh, logical_spec
 
@@ -180,11 +179,11 @@ def moe_apply_ep_local(p: Dict, x: jnp.ndarray, cfg: ModelConfig
         y = jax.lax.psum(y, "model")
         return y.reshape(Bl, Tl, D)
 
-    fn = shard_map(
+    fn = jax.shard_map(
         local_fn, mesh=mesh,
         in_specs=(bspec, P(None, None), P("model", None, None),
                   P("model", None, None), P("model", None, None)),
-        out_specs=bspec, check_rep=False)
+        out_specs=bspec, check_vma=False)
     y = fn(x, p["router"], p["w1"], p["w3"], p["w2"])
     if "shared" in p:
         y = y + mlp_apply(p["shared"], x.reshape(N, D), cfg).reshape(B, T, D)

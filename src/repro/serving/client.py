@@ -33,7 +33,9 @@ class StreamResult:
     events: List[Dict]          # parsed chunk JSONs, [DONE] excluded
     ttft_s: float               # connect → first SSE chunk
     total_s: float
-    error: Optional[Dict] = None   # error envelope on non-200
+    # error envelope: the body of a non-200, or an error event that ended
+    # a 200 stream early (the server failed mid-stream)
+    error: Optional[Dict] = None
 
     @property
     def tokens(self) -> List[int]:
@@ -74,8 +76,15 @@ def _encode_request(method: str, path: str, body: bytes) -> bytes:
 
 
 async def request(host: str, port: int, method: str, path: str,
-                  payload: Optional[Dict] = None) -> HttpResponse:
-    """One non-streaming HTTP request (Connection: close framing)."""
+                  payload: Optional[Dict] = None,
+                  timeout_s: Optional[float] = None) -> HttpResponse:
+    """One non-streaming HTTP request (Connection: close framing).
+    ``timeout_s`` bounds the whole exchange (``asyncio.TimeoutError``)."""
+    return await asyncio.wait_for(_request(host, port, method, path,
+                                           payload), timeout_s)
+
+
+async def _request(host, port, method, path, payload) -> HttpResponse:
     reader, writer = await asyncio.open_connection(host, port)
     try:
         body = json.dumps(payload).encode() if payload is not None else b""
@@ -96,8 +105,16 @@ async def request(host: str, port: int, method: str, path: str,
 
 
 async def stream_completion(host: str, port: int, payload: Dict,
-                            path: str = "/v1/completions") -> StreamResult:
-    """POST a streaming completion and consume the SSE stream fully."""
+                            path: str = "/v1/completions",
+                            timeout_s: Optional[float] = None
+                            ) -> StreamResult:
+    """POST a streaming completion and consume the SSE stream fully.
+    ``timeout_s`` bounds the whole stream (``asyncio.TimeoutError``)."""
+    return await asyncio.wait_for(_stream_completion(host, port, payload,
+                                                     path), timeout_s)
+
+
+async def _stream_completion(host, port, payload, path) -> StreamResult:
     t0 = time.perf_counter()
     reader, writer = await asyncio.open_connection(host, port)
     try:
@@ -131,9 +148,14 @@ async def stream_completion(host: str, port: int, payload: Dict,
                     if data == b"[DONE]":
                         return StreamResult(status, headers, events, ttft,
                                             time.perf_counter() - t0)
+                    event = json.loads(data)
+                    if "error" in event:
+                        return StreamResult(status, headers, events, ttft,
+                                            time.perf_counter() - t0,
+                                            error=event)
                     if not events:
                         ttft = time.perf_counter() - t0
-                    events.append(json.loads(data))
+                    events.append(event)
         return StreamResult(status, headers, events, ttft,
                             time.perf_counter() - t0)
     finally:

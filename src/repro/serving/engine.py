@@ -738,6 +738,9 @@ class BatchedDecoder:
         # never sees.
         self._view_key: Optional[tuple] = None
         self._views: Optional[dict] = None
+        # the last tick's logits [B, vocab] as brought to the host for the
+        # argmax: what a reference comparison reads
+        self.last_logits: Optional[np.ndarray] = None
 
     def _span(self, name: str, **args):
         """Named decoder-phase span (no-op without a tracer) — slot
@@ -877,6 +880,7 @@ class BatchedDecoder:
             logits = np.asarray(outs["logits"].cols["v"]).reshape(
                 bucket, -1)[:B, : eng.spec.vocab]
             next_toks = [int(t) for t in np.argmax(logits, axis=1)]
+        self.last_logits = logits
         if metrics is not None:
             metrics.histogram(
                 "decoder_tick_seconds",

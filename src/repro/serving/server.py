@@ -19,7 +19,8 @@ Endpoints (stdlib asyncio streams — no HTTP framework):
                                     OpenMetrics (with trace-id exemplars)
                                     via Accept negotiation or
                                     ``?format=openmetrics``
-  ``GET  /healthz``               — liveness + queue depth
+  ``GET  /healthz``               — liveness + queue depth; 503 once
+                                    the scheduler thread has failed
   ``GET  /debug/flight``          — flight-recorder dump: the last N
                                     prefill/decode ticks + event log
   ``GET  /debug/trace/{id}``      — one request's end-to-end Chrome
@@ -42,6 +43,11 @@ defaults, per-request ``*_slo_ms`` overrides) recorded as
 violation counters and fed to the scheduler's preemption victim choice —
 requests already past deadline are evicted first.
 
+Scheduler failure: an exception out of a scheduler tick (a device
+out-of-memory, a bad plan) stops the scheduler thread, fails every
+in-flight and queued request with an error, rejects new ones with 503,
+and turns ``/healthz`` to 503 — a request never waits on a dead loop.
+
 Streaming-side dedupe guard: each request tracks how many tokens were
 delivered; the emit hook only forwards ``generated[delivered:]``, so even
 a scheduler that replayed tokens (the pre-fix preemption behaviour)
@@ -55,12 +61,13 @@ import dataclasses
 import json
 import threading
 import time
+import traceback
 from collections import deque
 from typing import Deque, Dict, Optional, Tuple
 
 from repro.obs.context import new_trace_id
 from repro.obs.flight import FlightRecorder
-from repro.obs.log import set_flight_recorder
+from repro.obs.log import log_event, set_flight_recorder
 from repro.obs.metrics import (OPENMETRICS_CONTENT_TYPE,
                                PROMETHEUS_CONTENT_TYPE)
 from repro.serving import api
@@ -151,6 +158,7 @@ class AsyncLLMServer:
         self._cond = threading.Condition()
         self._next_rid = 0
         self._stop = False
+        self.failure: Optional[str] = None  # set once the scheduler died
         self._loop: Optional[asyncio.AbstractEventLoop] = None
         self._http: Optional[asyncio.base_events.Server] = None
         self._sched_thread: Optional[threading.Thread] = None
@@ -166,11 +174,29 @@ class AsyncLLMServer:
                     self.batcher.submit(self._pending.popleft())
                 if self._stop:
                     return
-            more = self.batcher.tick()
+            try:
+                more = self.batcher.tick()
+            except Exception as e:  # the loop cannot go on: fail loudly
+                self._fail(e)
+                return
             if not more:
                 with self._cond:
                     if not self._pending and not self._stop:
                         self._cond.wait(timeout=self.cfg.idle_wait_s)
+
+    def _fail(self, exc: Exception) -> None:
+        """Scheduler-thread death: record it, then end every open stream
+        with an error (in-flight, queued and not yet submitted alike)."""
+        traceback.print_exception(exc)
+        msg = f"scheduler failed: {type(exc).__name__}: {exc}"
+        log_event("scheduler_failed", error=msg)
+        with self._cond:
+            self.failure = msg
+            streams = list(self._streams.values())
+        if self._loop is not None:
+            for stream in streams:
+                self._loop.call_soon_threadsafe(
+                    stream.queue.put_nowait, ("error", None))
 
     def _on_token(self, req: Request, tok: int) -> None:
         """Scheduler-thread hook: forward newly generated tokens.
@@ -226,6 +252,11 @@ class AsyncLLMServer:
                 429, "serving queue is full, retry later",
                 code="saturated", retry_after_s=cfg.retry_after_s)
         with self._cond:
+            # under the lock _fail() takes: a request admitted after the
+            # failure is rejected, one admitted before it gets its error
+            if self.failure is not None:
+                raise api.ApiError(503, self.failure,
+                                   code="scheduler_failed")
             rid = self._next_rid
             self._next_rid += 1
             req = Request(
@@ -261,7 +292,8 @@ class AsyncLLMServer:
                               ) -> None:
         reason = {200: "OK", 400: "Bad Request", 404: "Not Found",
                   405: "Method Not Allowed", 429: "Too Many Requests",
-                  500: "Internal Server Error"}.get(status, "OK")
+                  500: "Internal Server Error",
+                  503: "Service Unavailable"}.get(status, "OK")
         head = [f"HTTP/1.1 {status} {reason}",
                 f"Content-Type: {content_type}",
                 f"Content-Length: {len(body)}", "Connection: close"]
@@ -359,6 +391,10 @@ class AsyncLLMServer:
             await self._write_response(writer, 200, text.encode(), ctype)
             return 200
         if path == "/healthz" and method == "GET":
+            if self.failure is not None:
+                await self._write_json(
+                    writer, 503, {"status": "failed", "error": self.failure})
+                return 503
             await self._write_json(
                 writer, 200,
                 {"status": "ok", "queue_depth": self._queue_depth(),
@@ -411,16 +447,22 @@ class AsyncLLMServer:
     # -- completion endpoints ------------------------------------------------
 
     async def _collect(self, stream: _Stream):
-        """Yield ('token', id) items until the request completes."""
+        """Yield token ids until the request completes; raise a 503
+        ``ApiError`` if the scheduler failed under it."""
         while True:
             kind, value = await stream.queue.get()
             if kind == "done":
                 return
+            if kind == "error":
+                raise api.ApiError(503, self.failure or "scheduler failed",
+                                   code="scheduler_failed")
             yield value
 
     async def _blocking_completion(self, writer, parsed, stream) -> None:
-        tokens = [t async for t in self._collect(stream)]
-        self._streams.pop(stream.req.rid, None)
+        try:
+            tokens = [t async for t in self._collect(stream)]
+        finally:
+            self._streams.pop(stream.req.rid, None)
         await self._write_json(
             writer, 200,
             api.completion_response(stream.req.rid, self.cfg.model_id,
@@ -440,14 +482,21 @@ class AsyncLLMServer:
                                "open SSE responses").inc()
         try:
             index = 0
-            async for tok in self._collect(stream):
-                last = index + 1 >= parsed.max_tokens
-                writer.write(api.sse_event(api.stream_chunk(
-                    stream.req.rid, self.cfg.model_id, parsed, tok, index,
-                    self.tokenizer, finish=last,
-                    trace_id=stream.req.trace_id)))
+            try:
+                async for tok in self._collect(stream):
+                    last = index + 1 >= parsed.max_tokens
+                    writer.write(api.sse_event(api.stream_chunk(
+                        stream.req.rid, self.cfg.model_id, parsed, tok,
+                        index, self.tokenizer, finish=last,
+                        trace_id=stream.req.trace_id)))
+                    await writer.drain()
+                    index += 1
+            except api.ApiError as e:
+                # the 200 head is already out: end the stream with an
+                # error event and no [DONE]
+                writer.write(api.sse_event(e.to_dict()))
                 await writer.drain()
-                index += 1
+                return
             writer.write(api.SSE_DONE)
             await writer.drain()
         finally:

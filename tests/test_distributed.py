@@ -16,8 +16,7 @@ import json
 import jax
 import jax.numpy as jnp
 import numpy as np
-from jax.sharding import NamedSharding, PartitionSpec as P
-from jax.experimental.shard_map import shard_map
+from jax.sharding import AxisType, NamedSharding, PartitionSpec as P
 
 from repro.configs import get_config
 from repro.data.pipeline import SyntheticLM
@@ -70,8 +69,8 @@ with mesh3, sharding_rules(mesh3, multi_pod_rules(fsdp=True)):
 results["pjit_multipod_loss_delta"] = abs(loss_single - float(m3["loss"]))
 
 # ---- 3. elastic reshard: save on 2x2, restore on 2x2x2 ----------------------
-ckpt_dir = "/tmp/repro_elastic_test"
-import shutil; shutil.rmtree(ckpt_dir, ignore_errors=True)
+import tempfile
+ckpt_dir = tempfile.mkdtemp(prefix="repro_elastic_")
 ckpt.save(ckpt_dir, 1, p2)
 abstract = jax.tree_util.tree_map(
     lambda l: jax.ShapeDtypeStruct(l.shape, l.dtype), params)
@@ -84,9 +83,10 @@ d = jax.tree_util.tree_map(
         np.asarray(jax.device_get(a), np.float32)
         - np.asarray(jax.device_get(b), np.float32)))), p2, restored)
 results["elastic_reshard_delta"] = max(jax.tree_util.tree_leaves(d))
+import shutil; shutil.rmtree(ckpt_dir, ignore_errors=True)
 
 # ---- 4. pipeline parallelism: 4 stages == dense ------------------------------
-pmesh = jax.make_mesh((4,), ("pod",))
+pmesh = jax.make_mesh((4,), ("pod",), axis_types=(AxisType.Auto,))
 L, D = 8, 16
 keys = jax.random.split(jax.random.PRNGKey(1), L)
 blocks = {"w": jax.vmap(lambda k: jax.random.normal(k, (D, D)) * 0.1)(keys)}
@@ -112,8 +112,8 @@ def hier(x):
 def plain(x):
     return jax.lax.psum(x, ("pod", "data"))
 
-sm = lambda f: shard_map(f, mesh=mesh3b, in_specs=P(None, "model"),
-                         out_specs=P(None, "model"), check_rep=False)
+sm = lambda f: jax.shard_map(f, mesh=mesh3b, in_specs=P(None, "model"),
+                             out_specs=P(None, "model"), check_vma=False)
 a = sm(hier)(xs)
 b = sm(plain)(xs)
 results["hier_psum_delta"] = float(jnp.max(jnp.abs(a - b)))
@@ -124,9 +124,9 @@ err0 = jnp.zeros((4, 8))
 def efar(g, e):
     return ef_int8_allreduce(g, e, ("data",))
 
-mean_g, new_err = shard_map(
+mean_g, new_err = jax.shard_map(
     efar, mesh=mesh3b, in_specs=(P(None, None), P(None, None)),
-    out_specs=(P(None, None), P(None, None)), check_rep=False)(g, err0)
+    out_specs=(P(None, None), P(None, None)), check_vma=False)(g, err0)
 # all shards hold the same g ⇒ mean == g up to int8 quantisation error
 results["ef_int8_error"] = float(jnp.max(jnp.abs(mean_g - g)))
 results["ef_feedback_nonzero"] = float(jnp.max(jnp.abs(new_err)))

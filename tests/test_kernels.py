@@ -1,5 +1,7 @@
 """Pallas kernel validation: shape/dtype sweeps vs the ref.py oracles,
-executed in interpret mode on CPU."""
+executed in interpret mode on the CPU backend (the wrappers interpret only
+when asked).  tests/test_tpu_compile.py compiles the same kernels for a
+described TPU at model widths."""
 
 import numpy as np
 import jax.numpy as jnp
@@ -22,7 +24,7 @@ class TestChunkedMatmul:
     def test_sweep(self, m, n, k, dtype):
         x = jnp.asarray(RNG.standard_normal((m, k)), dtype)
         w = jnp.asarray(RNG.standard_normal((n, k)), dtype)
-        got = ops.chunked_matmul(x, w, bm=32, bn=32, bk=32)
+        got = ops.chunked_matmul(x, w, bm=32, bn=32, bk=32, interpret=True)
         want = ref.chunked_matmul(x, w)
         np.testing.assert_allclose(np.asarray(got, np.float32),
                                    np.asarray(want, np.float32), **_tol(dtype))
@@ -32,7 +34,7 @@ class TestChunkedMatmul:
         """The relational chunk size (= bk) never changes the result."""
         x = jnp.asarray(RNG.standard_normal((64, 128)), jnp.float32)
         w = jnp.asarray(RNG.standard_normal((48, 128)), jnp.float32)
-        got = ops.chunked_matmul(x, w, bm=32, bn=16, bk=bk)
+        got = ops.chunked_matmul(x, w, bm=32, bn=16, bk=bk, interpret=True)
         np.testing.assert_allclose(np.asarray(got),
                                    np.asarray(ref.chunked_matmul(x, w)),
                                    rtol=2e-5, atol=2e-5)
@@ -47,7 +49,8 @@ class TestFlashAttention:
         q = jnp.asarray(RNG.standard_normal((2, 2, T, d)), dtype)
         k = jnp.asarray(RNG.standard_normal((2, 2, S, d)), dtype)
         v = jnp.asarray(RNG.standard_normal((2, 2, S, d)), dtype)
-        got = ops.flash_attention(q, k, v, causal=causal, bq=16, bk=16)
+        got = ops.flash_attention(q, k, v, causal=causal, bq=16, bk=16,
+                                  interpret=True)
         want = ref.flash_attention(q, k, v, causal=causal)
         np.testing.assert_allclose(np.asarray(got, np.float32),
                                    np.asarray(want, np.float32), **_tol(dtype))
@@ -56,8 +59,8 @@ class TestFlashAttention:
         q = jnp.asarray(RNG.standard_normal((1, 2, 64, 32)), jnp.float32)
         k = jnp.asarray(RNG.standard_normal((1, 2, 64, 32)), jnp.float32)
         v = jnp.asarray(RNG.standard_normal((1, 2, 64, 32)), jnp.float32)
-        a = ops.flash_attention(q, k, v, bq=16, bk=16)
-        b = ops.flash_attention(q, k, v, bq=64, bk=32)
+        a = ops.flash_attention(q, k, v, bq=16, bk=16, interpret=True)
+        b = ops.flash_attention(q, k, v, bq=64, bk=32, interpret=True)
         np.testing.assert_allclose(np.asarray(a), np.asarray(b), rtol=2e-5,
                                    atol=2e-5)
 
@@ -76,7 +79,8 @@ class TestPagedAttention:
             for i in range(-(-lens[b] // page)):
                 pt[b, i] = next(used)
         lens_a = jnp.asarray(lens, jnp.int32)
-        got = ops.paged_attention(q, kp, vp, jnp.asarray(pt), lens_a)
+        got = ops.paged_attention(q, kp, vp, jnp.asarray(pt), lens_a,
+                                  interpret=True)
         want = ref.paged_attention(q, kp, vp, jnp.asarray(pt), lens_a)
         np.testing.assert_allclose(np.asarray(got, np.float32),
                                    np.asarray(want, np.float32), **_tol(dtype))
@@ -105,7 +109,8 @@ class TestPagedAttention:
         qlast = jnp.asarray(RNG.standard_normal((B, H, d)), jnp.float32)
         got = ops.paged_attention(qlast, jnp.asarray(kp), jnp.asarray(vp),
                                   jnp.asarray(pt),
-                                  jnp.asarray([T, T], jnp.int32))
+                                  jnp.asarray([T, T], jnp.int32),
+                                  interpret=True)
         # dense reference: full attention of the single query over T tokens
         kk = jnp.repeat(k, H // Hkv, axis=2).transpose(0, 2, 1, 3)
         vv = jnp.repeat(v, H // Hkv, axis=2).transpose(0, 2, 1, 3)

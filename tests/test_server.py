@@ -234,6 +234,32 @@ class TestHttpApi:
             assert resp.status == 200
             assert resp.json()["status"] == "ok"
 
+    def test_scheduler_failure_fails_requests(self, engine):
+        """An exception out of a scheduler tick ends the in-flight stream
+        with an error instead of hanging it, fails /healthz and rejects
+        new requests."""
+        with _server(engine) as srv:
+            def decode_raises(seq_ids, last_tokens):
+                raise RuntimeError("device lost")
+
+            srv.batcher.decode_fn = decode_raises
+            host, port = srv.cfg.host, srv.port
+            res = run(client.stream_completion(
+                host, port, {"prompt": [1, 2, 3], "max_tokens": 4},
+                timeout_s=30))
+            assert res.status == 200  # the prefill token went out first
+            assert len(res.tokens) == 1
+            assert res.error["error"]["code"] == "scheduler_failed"
+            assert "device lost" in res.error["error"]["message"]
+            health = run(client.request(host, port, "GET", "/healthz",
+                                        timeout_s=30))
+            assert health.status == 503
+            assert health.json()["status"] == "failed"
+            late = run(client.request(
+                host, port, "POST", "/v1/completions",
+                {"prompt": [1, 2], "max_tokens": 2}, timeout_s=30))
+            assert late.status == 503
+
 
 async def _raw_get(host, port, path, extra_headers=""):
     """GET with caller-controlled headers (the stdlib client pins its

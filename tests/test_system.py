@@ -57,6 +57,37 @@ def test_direct_paged_matches(weights, direct_tokens, tmp_path):
     assert res.tokens == direct_tokens
 
 
+@pytest.mark.parametrize("name,n_layers", [("llama3-8b", 4),
+                                           ("llama3-8b", None),
+                                           ("llama3-tiny", None)])
+def test_config_to_spec_round_trips(name, n_layers):
+    """A registered config builds the relational spec at its published
+    widths, depth cut only, and maps back through spec_to_config."""
+    from repro.configs import get_config
+    from repro.core.bridge import config_to_spec
+    cfg = get_config(name)
+    spec = config_to_spec(cfg, n_layers)
+    assert spec.n_layers == (n_layers or cfg.n_layers)
+    back = spec_to_config(spec)
+    for f in ("d_model", "n_heads", "n_kv", "head_dim", "d_ff", "vocab",
+              "rope_theta", "eps"):
+        assert getattr(back, f) == getattr(cfg, f), f
+    assert back.n_layers == spec.n_layers
+    if name == "llama3-8b":
+        assert (spec.d_model, spec.n_heads, spec.n_kv, spec.head_dim,
+                spec.d_ff, spec.vocab, spec.rope_theta) == \
+            (4096, 32, 8, 128, 14336, 128256, 5e5)
+
+
+def test_config_to_spec_rejects_other_families():
+    from repro.configs import get_config
+    from repro.core.bridge import config_to_spec
+    with pytest.raises(ValueError, match="qk_norm"):
+        config_to_spec(get_config("qwen3-14b"))
+    with pytest.raises(ValueError, match="n_layers"):
+        config_to_spec(get_config("llama3-8b"), 33)
+
+
 def test_chunk_size_only_affects_speed(weights, direct_tokens):
     """Tab. 1's knob: every chunk size yields identical generations."""
     for cs in (4, 8, 16):

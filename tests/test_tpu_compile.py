@@ -1,0 +1,76 @@
+"""Compile the Pallas kernels for a described TPU v5e at Llama-3-8B widths.
+
+Nothing runs: the TPU compiler that ships with libtpu lowers each kernel
+for a chip that is described, not attached, and the test checks that
+Mosaic accepted it (a ``tpu_custom_call`` in the compiled text).  This
+catches what interpret mode cannot: tiling that the chip refuses, or more
+fast memory than a kernel may use.
+
+The topology is described inside a module-scoped fixture, never at import
+time: only one process at a time may load the TPU library, and the test
+workers all import this file.  Where it cannot be described, the tests
+skip from the fixture.
+"""
+
+import functools
+import os
+
+import jax
+import jax.numpy as jnp
+import pytest
+from jax.sharding import SingleDeviceSharding
+
+from repro.configs.llama3_8b import CONFIG as LLAMA3_8B
+from repro.kernels import ops
+
+
+@pytest.fixture(scope="module")
+def one_chip():
+    os.environ.setdefault("TPU_LOG_DIR", "disabled")
+    from jax.experimental import topologies
+    try:
+        topo = topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as e:  # no libtpu, or it is held by another process
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+    # a compile for a described chip is written to the persistent cache
+    # but cannot be read back without one: keep the cache out of it
+    prev = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    yield SingleDeviceSharding(topo.devices[0])
+    jax.config.update("jax_enable_compilation_cache", prev)
+
+
+def _compiled_text(fn, *shapes, sharding):
+    args = [jax.ShapeDtypeStruct(s, dt, sharding=sharding) for s, dt in shapes]
+    return jax.jit(fn).lower(*args).compile().as_text()
+
+
+def test_chunked_matmul_compiles_at_ffn_width(one_chip):
+    """One prefill block of 256 tokens against the FFN up-projection."""
+    c = LLAMA3_8B
+    fn = functools.partial(ops.chunked_matmul, bm=128, bn=128, bk=128)
+    text = _compiled_text(fn, ((256, c.d_model), jnp.float32),
+                          ((c.d_ff, c.d_model), jnp.float32),
+                          sharding=one_chip)
+    assert "tpu_custom_call" in text
+
+
+def test_flash_attention_compiles_at_head_width(one_chip):
+    c = LLAMA3_8B
+    shape = ((1, c.n_heads, 2048, c.head_dim), jnp.float32)
+    fn = functools.partial(ops.flash_attention, causal=True)
+    text = _compiled_text(fn, shape, shape, shape, sharding=one_chip)
+    assert "tpu_custom_call" in text
+
+
+def test_paged_attention_compiles_at_page_geometry(one_chip):
+    """A decode batch of 4 over 16-token pages of the 8 KV heads."""
+    c = LLAMA3_8B
+    B, page, n_pages, max_pages = 4, 16, 256, 32
+    pool = ((n_pages, page, c.n_kv, c.head_dim), jnp.float32)
+    text = _compiled_text(ops.paged_attention,
+                          ((B, c.n_heads, c.head_dim), jnp.float32),
+                          pool, pool, ((B, max_pages), jnp.int32),
+                          ((B,), jnp.int32), sharding=one_chip)
+    assert "tpu_custom_call" in text
