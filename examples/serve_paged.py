@@ -38,7 +38,7 @@ def print_metrics_summary(reg: MetricsRegistry) -> None:
         return getattr(reg, kind)(name, **labels)
 
     ttft = get("histogram", "serving_ttft_seconds")
-    tick = get("histogram", "serving_tick_seconds")
+    tick = get("histogram", "decoder_tick_seconds")
     occ = get("gauge", "serving_batch_occupancy")
     hits = get("counter", "pager_hits_total").value
     pf_hits = get("counter", "pager_prefetch_hits_total").value

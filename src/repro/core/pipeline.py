@@ -14,6 +14,7 @@ import jax.numpy as jnp
 
 from repro.core.executor import DenseTable, execute, plan_provenance
 from repro.core.opmap import RelPipeline
+from repro.obs.trace import traced
 
 
 def run_pipeline(
@@ -37,12 +38,17 @@ def run_pipeline(
     Scans see the declared physical schema; pass ``layout_plan`` to
     override the plan recorded on the pipeline.
 
+    Every step runs inside a ``step.<name>`` profiler annotation and a
+    ``jax.named_scope(<name>)``: neither waits on the device, the
+    annotation is free when no profile is being captured, and under
+    ``jit`` the scope names the step's device operations.
+
     ``tracer`` (an ``Optional[repro.obs.trace.TraceRecorder]``) records one
     ``cat="step"`` span per pipeline step, blocking on the step's result so
     the span measures real compute (JAX dispatch is asynchronous), plus the
     executor's per-node ``cat="op"`` sub-spans.  With ``tracer=None`` (the
-    default) the only cost is one ``None`` check per step — tracing must
-    not be enabled under ``jit`` (the block would fail on traced values).
+    default) nothing blocks — a recorder must not be attached under
+    ``jit`` (the block would fail on traced values).
 
     ``shard_runner`` (e.g. ``repro.serving.shards.ShardWorkerPool.
     run_step``) takes over bind steps the pipeline's shard plan split
@@ -125,11 +131,14 @@ def run_pipeline(
 
     for step in pipeline.steps:
         if tracer is None:
-            _run_step(step)
+            with traced(f"step.{step.name}"), jax.named_scope(step.name):
+                _run_step(step)
         else:
             ops, tables = plan_provenance(step.rel.plan)
-            with tracer.span(step.name, cat="step", kind=step.kind,
-                             ops=list(ops), tables=list(tables)):
+            with traced(f"step.{step.name}",
+                        tracer.span(step.name, cat="step", kind=step.kind,
+                                    ops=list(ops), tables=list(tables))), \
+                    jax.named_scope(step.name):
                 _run_step(step)
                 # block so the span measures compute, not dispatch
                 jax.block_until_ready(list(env[step.name].cols.values()))

@@ -32,7 +32,16 @@ from repro.core.graph import infer_shapes
 from repro.core.opmap import op_map
 from repro.core.passes import postoptimize, preoptimize
 from repro.core.pipeline import run_pipeline
+from repro.obs.trace import traced
 from repro.serving.pager import WeightPager
+
+# host-clock histograms of one served phase, labelled phase="prefill" or
+# phase="decode": the run_pipeline call, which dispatches the plan, and
+# the copy of its logits to the host, where the host waits for the device
+PHASE_SERIES = {
+    "engine_dispatch_seconds": "host time in one run_pipeline call",
+    "engine_sync_seconds": "host wait for a plan's logits",
+}
 
 
 @dataclasses.dataclass
@@ -500,6 +509,32 @@ class RelationalEngine:
         return np.asarray(out_table.cols["v"]).reshape(
             out_table.cols["v"].shape[0], -1)[-1, : self.spec.vocab]
 
+    def _phase_hist(self, series: str, phase: str):
+        """The ``phase`` histogram of one of ``PHASE_SERIES``; None
+        without a registry."""
+        if self.metrics is None:
+            return None
+        return self.metrics.histogram(series, PHASE_SERIES[series],
+                                      phase=phase)
+
+    def _run_prefill(self, T: int, suffix: bool, env, position: int):
+        """The prefill plan over ``env`` and its final-position logits on
+        the host; returns ``(env, logits)``."""
+        if self.pager is not None:
+            self.pager.prefetch(["vocabulary"])
+        with traced("prefill.dispatch",
+                    hist=self._phase_hist("engine_dispatch_seconds",
+                                          "prefill")):
+            outs, env = run_pipeline(self._prefill_pipe(T, suffix=suffix),
+                                     env,
+                                     scalars={"cache_position": position},
+                                     tracer=self.tracer,
+                                     shard_runner=self._shard_runner)
+        with traced("prefill.sync",
+                    hist=self._phase_hist("engine_sync_seconds", "prefill")):
+            logits = self._final_logits(outs["logits"])
+        return env, logits
+
     @property
     def table_precision_choices(self) -> Dict[str, str]:
         """Planner-chosen payload precision per stored weight table (the
@@ -572,13 +607,7 @@ class RelationalEngine:
             env["token_ids"] = lg.token_table(np.asarray(prompt, np.int32))
             env["freq_each_token"] = lg.rope_freq_table(
                 np.arange(T), self.spec.head_dim, self.spec.rope_theta)
-        if self.pager is not None:
-            self.pager.prefetch(["vocabulary"])
-        outs, env = run_pipeline(self._prefill_pipe(T), env,
-                                 scalars={"cache_position": 0},
-                                 tracer=self.tracer,
-                                 shard_runner=self._shard_runner)
-        logits = self._final_logits(outs["logits"])
+        env, logits = self._run_prefill(T, False, env, 0)
         return {"env": env, "pos": T, "tok": int(np.argmax(logits)),
                 "logits": logits}
 
@@ -614,13 +643,7 @@ class RelationalEngine:
             env["freq_each_token"] = lg.rope_freq_table(
                 np.arange(boundary, len(prompt)), self.spec.head_dim,
                 self.spec.rope_theta)
-        if self.pager is not None:
-            self.pager.prefetch(["vocabulary"])
-        outs, env = run_pipeline(self._prefill_pipe(T, suffix=True), env,
-                                 scalars={"cache_position": boundary},
-                                 tracer=self.tracer,
-                                 shard_runner=self._shard_runner)
-        logits = self._final_logits(outs["logits"])
+        env, logits = self._run_prefill(T, True, env, boundary)
         return {"env": env, "pos": len(prompt),
                 "tok": int(np.argmax(logits)), "logits": logits}
 
@@ -758,7 +781,7 @@ class BatchedDecoder:
         # generation, invalidating any cached batch view over it
         self._unbind(seq_id)
         sess = self.engine.start_session(list(prompt))
-        with self._span("cache_fill"):
+        with traced("prefill.cache_fill", self._span("cache_fill")):
             self.pool.write_prefill(seq_id, sess["env"], len(prompt))
         return sess["tok"]
 
@@ -784,14 +807,15 @@ class BatchedDecoder:
             hit = pc.lookup(prompt)
         if hit is None:
             sess = self.engine.start_session(prompt)
-            with self._span("cache_fill"):
+            with traced("prefill.cache_fill", self._span("cache_fill")):
                 self.pool.write_prefill(seq_id, sess["env"], len(prompt))
                 pc.insert(prompt, sess["env"])
             return sess["tok"], 0
         seg, boundary = hit
         sess = self.engine.start_suffix_session(prompt, boundary,
                                                 seg.tables)
-        with self._span("cache_fill", prefix_hit=boundary):
+        with traced("prefill.cache_fill",
+                    self._span("cache_fill", prefix_hit=boundary)):
             if self._resolve_bind(boundary) == "share":
                 # slot holds only the divergent suffix; gathers splice the
                 # segment's rows in (UNION-remap); the segment stays pinned
@@ -834,7 +858,9 @@ class BatchedDecoder:
         metrics = eng.metrics
         # decoder-phase spans (cat="decoder") name the tick's work outside
         # run_pipeline — view gathers, cache writeback, logits extraction —
-        # so a flight-recorded tick attributes its wall time end to end
+        # so a flight-recorded tick attributes its wall time end to end;
+        # the decode.* profiler annotations split the same tick into
+        # views, dispatch, writeback and the wait for the logits
         span = (eng.tracer.span if eng.tracer is not None
                 else (lambda *a, **k: contextlib.nullcontext()))
         t0 = time.perf_counter() if metrics is not None else 0.0
@@ -846,8 +872,9 @@ class BatchedDecoder:
         positions = self.pool.positions[np.asarray(ids)]
         view_key = (tuple(ids), self.pool.slot_generations(ids))
         view_hit = self._view_key == view_key
-        with span("cache_views", cat="decoder",
-                  outcome="hit" if view_hit else "miss"):
+        with traced("decode.views",
+                    span("cache_views", cat="decoder",
+                         outcome="hit" if view_hit else "miss")):
             env = eng._weights_env()
             if view_hit:
                 env.update(self._views)  # unchanged batch: reuse last views
@@ -862,23 +889,30 @@ class BatchedDecoder:
             metrics.counter("decoder_view_cache_total",
                             "batched cache-view gathers",
                             outcome="hit" if view_hit else "miss").inc()
-        outs, env = run_pipeline(
-            pipe, env,
-            scalars={"seq_positions": jnp.asarray(positions, jnp.int32)},
-            tracer=eng.tracer, shard_runner=eng._shard_runner)
+        with traced("decode.dispatch",
+                    hist=eng._phase_hist("engine_dispatch_seconds",
+                                         "decode")):
+            outs, env = run_pipeline(
+                pipe, env,
+                scalars={"seq_positions": jnp.asarray(positions, jnp.int32)},
+                tracer=eng.tracer, shard_runner=eng._shard_runner)
         self.decode_calls += 1
         # the tick's only cache mutation is one appended row per sequence
         # at positions[b] — write back just those rows; the updated views
         # (which already contain them) serve the next tick's gather
-        with span("cache_writeback", cat="decoder"):
+        with traced("decode.writeback", span("cache_writeback",
+                                             cat="decoder")):
             self.pool.scatter_rows(ids, env, positions)
             self._views = {name: env[name] for name in self.pool.tables}
             self._view_key = view_key
             for s in seq_ids:
                 self.pool.positions[s] += 1
         with span("logits_argmax", cat="decoder"):
-            logits = np.asarray(outs["logits"].cols["v"]).reshape(
-                bucket, -1)[:B, : eng.spec.vocab]
+            with traced("decode.sync",
+                        hist=eng._phase_hist("engine_sync_seconds",
+                                             "decode")):
+                logits = np.asarray(outs["logits"].cols["v"])
+            logits = logits.reshape(bucket, -1)[:B, : eng.spec.vocab]
             next_toks = [int(t) for t in np.argmax(logits, axis=1)]
         self.last_logits = logits
         if metrics is not None:
@@ -886,9 +920,6 @@ class BatchedDecoder:
                 "decoder_tick_seconds",
                 "batched decode tick latency").observe(
                     time.perf_counter() - t0)
-            metrics.gauge("decoder_bucket_occupancy",
-                          "live sequences / padded bucket size").set(
-                              B / bucket)
         return next_toks
 
 

@@ -23,6 +23,7 @@ from collections import deque
 from typing import Callable, Deque, Dict, List, Optional
 
 from repro.obs.context import TraceContext, activate, new_trace_id
+from repro.obs.trace import traced
 from repro.serving.kvcache import PagedKVCache
 
 
@@ -31,7 +32,9 @@ class Request:
     rid: int
     prompt: List[int]
     max_new_tokens: int
-    arrival_s: float = 0.0
+    # host perf_counter when the request first exists: stamped at HTTP
+    # admission, else by submit(); TTFT, queue wait and deadlines start here
+    arrival_s: Optional[float] = None
     # end-to-end trace id: minted at HTTP admission (or at submit() when
     # the front end didn't) and carried through every span / DB operator
     # the request touches — the key of /debug/trace/{id}
@@ -143,9 +146,9 @@ class ContinuousBatcher:
         self.active: Dict[int, Request] = {}   # seq_id -> request
         self.finished: List[Request] = []
         self.stats = SchedulerStats()
-        # optional repro.obs.metrics.MetricsRegistry: TTFT / TPOT / tick
-        # histograms, occupancy gauge, preemption + completion + SLO
-        # violation counters
+        # optional repro.obs.metrics.MetricsRegistry: TTFT / TPOT / queue
+        # wait / prefill histograms, occupancy gauge, decoded-token,
+        # preemption + completion + SLO violation counters
         self.metrics = metrics
         self.on_token = on_token
         self.on_done = on_done
@@ -169,7 +172,8 @@ class ContinuousBatcher:
             self.release_fn(seq_id)
 
     def submit(self, req: Request) -> None:
-        req.arrival_s = time.perf_counter()
+        if req.arrival_s is None:
+            req.arrival_s = time.perf_counter()
         if not req.trace_id:
             req.trace_id = new_trace_id()
         self.queue.append(req)
@@ -246,7 +250,17 @@ class ContinuousBatcher:
             n0 = (len(self.tracer.events) if self.tracer is not None
                   and self.flight is not None else 0)
             t0p = time.perf_counter()
-            with activate(pctx):
+            prefill_hist = None
+            if self.metrics is not None:
+                if req.first_token_s is None:  # first admission only
+                    self.metrics.histogram(
+                        "serving_queue_wait_seconds",
+                        "arrival to the start of the first prefill"
+                    ).observe(t0p - req.arrival_s, exemplar=req.trace_id)
+                prefill_hist = self.metrics.histogram(
+                    "serving_prefill_seconds", "one prefill callback")
+            with traced("sched.prefill", hist=prefill_hist, rid=req.rid), \
+                    activate(pctx):
                 res = self.prefill_fn(req, seq_id)
             if self.flight is not None:
                 spans = (self.tracer.drain(n0)
@@ -367,7 +381,7 @@ class ContinuousBatcher:
         n0 = (len(self.tracer.events) if self.tracer is not None
               and self.flight is not None else 0)
         t0 = time.perf_counter()
-        with activate(dctx):
+        with traced("sched.decode"), activate(dctx):
             next_tokens = self.decode_fn(seq_ids, last)
         t1 = time.perf_counter()
         self.stats.decode_steps += 1
@@ -378,12 +392,9 @@ class ContinuousBatcher:
                 tick=self.stats.ticks, request_ids=dctx.request_ids,
                 trace_ids=dctx.trace_ids)
         if self.metrics is not None:
-            self.metrics.histogram(
-                "serving_tick_seconds",
-                "decode tick latency").observe(t1 - t0)
-            self.metrics.gauge(
-                "serving_active_sequences",
-                "sequences in the running batch").set(len(seq_ids))
+            self.metrics.counter(
+                "serving_decoded_tokens_total",
+                "sequences advanced by decode ticks").inc(len(seq_ids))
             self.metrics.gauge(
                 "serving_batch_occupancy",
                 "active sequences / max_batch").set(
@@ -394,13 +405,14 @@ class ContinuousBatcher:
         for s in seq_ids:
             self.kv.seq_lens[s] += 1
 
-        for seq_id, tok in zip(seq_ids, next_tokens):
-            req = self.active[seq_id]
-            req.generated.append(int(tok))
-            self._emit(req, int(tok))
-            if len(req.generated) >= req.max_new_tokens:
-                self._finish(req, seq_id)
-                del self.active[seq_id]
+        with traced("sched.emit"):
+            for seq_id, tok in zip(seq_ids, next_tokens):
+                req = self.active[seq_id]
+                req.generated.append(int(tok))
+                self._emit(req, int(tok))
+                if len(req.generated) >= req.max_new_tokens:
+                    self._finish(req, seq_id)
+                    del self.active[seq_id]
         if self.watchdog is not None:
             # tick boundary: no plan is mid-flight, so the watchdog may
             # swap the engine's compiled pipelines here

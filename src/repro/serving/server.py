@@ -70,6 +70,7 @@ from repro.obs.flight import FlightRecorder
 from repro.obs.log import log_event, set_flight_recorder
 from repro.obs.metrics import (OPENMETRICS_CONTENT_TYPE,
                                PROMETHEUS_CONTENT_TYPE)
+from repro.obs.trace import GcSpans, traced
 from repro.serving import api
 from repro.serving.scheduler import ContinuousBatcher, Request
 from repro.serving.watchdog import DriftWatchdog
@@ -164,6 +165,9 @@ class AsyncLLMServer:
         self._sched_thread: Optional[threading.Thread] = None
         self._shutdown_ev: Optional[asyncio.Event] = None
         self.port: Optional[int] = None
+        # a "gc" profiler annotation around each Python collection while
+        # the server runs, so that a pause in a trace has a name
+        self._gc_spans = GcSpans()
 
     # -- scheduler thread ----------------------------------------------------
 
@@ -175,14 +179,16 @@ class AsyncLLMServer:
                 if self._stop:
                     return
             try:
-                more = self.batcher.tick()
+                with traced("sched.tick", tick=self.batcher.stats.ticks + 1):
+                    more = self.batcher.tick()
             except Exception as e:  # the loop cannot go on: fail loudly
                 self._fail(e)
                 return
             if not more:
                 with self._cond:
                     if not self._pending and not self._stop:
-                        self._cond.wait(timeout=self.cfg.idle_wait_s)
+                        with traced("sched.wait"):
+                            self._cond.wait(timeout=self.cfg.idle_wait_s)
 
     def _fail(self, exc: Exception) -> None:
         """Scheduler-thread death: record it, then end every open stream
@@ -262,6 +268,9 @@ class AsyncLLMServer:
             req = Request(
                 rid=rid, prompt=list(parsed.prompt),
                 max_new_tokens=parsed.max_tokens,
+                # the request exists from here: a wait in _pending for
+                # the scheduler thread counts in its TTFT and queue wait
+                arrival_s=time.perf_counter(),
                 # the end-to-end trace id is minted HERE, at HTTP
                 # admission — the earliest point the request exists —
                 # and returned on the response as an extension field
@@ -511,6 +520,7 @@ class AsyncLLMServer:
         """Bind the listening socket and start the scheduler thread."""
         self._loop = asyncio.get_running_loop()
         self._shutdown_ev = asyncio.Event()
+        self._gc_spans.install()
         self._sched_thread = threading.Thread(
             target=self._scheduler_loop, name="transql-scheduler",
             daemon=True)
@@ -540,6 +550,7 @@ class AsyncLLMServer:
         if self._http is not None:
             self._http.close()
             await self._http.wait_closed()
+        self._gc_spans.remove()
 
     # -- test/driver convenience ----------------------------------------------
 

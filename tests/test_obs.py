@@ -599,20 +599,24 @@ class TestServingMetricsSmoke:
         assert eng2.metrics is None and eng2.tracer is None
 
     def test_scheduler_metrics(self):
+        from repro.serving.engine import RelationalEngine
         from repro.serving.kvcache import PagedKVCache, PagedKVConfig
         from repro.serving.scheduler import ContinuousBatcher, Request
-        cfg = PagedKVConfig(n_layers=1, n_kv=1, head_dim=4, page_size=4,
+        cfg = PagedKVConfig(n_layers=SPEC.n_layers, n_kv=SPEC.n_kv,
+                            head_dim=SPEC.head_dim, page_size=4,
                             n_pages=16, max_pages_per_seq=4)
         kv = PagedKVCache(cfg, max_seqs=4)
         reg = MetricsRegistry()
+        eng = RelationalEngine(SPEC, init_llama_params(SPEC, seed=0),
+                               chunk_size=CS, max_len=8, metrics=reg)
+        dec = eng.batched_decoder(max_seqs=4)
 
         def prefill(req, seq_id):
             kv.ensure_capacity(seq_id, len(req.prompt))
-            return 1
+            return dec.prefill_ex(req.context, seq_id)
 
-        sched = ContinuousBatcher(kv, prefill,
-                                  lambda ids, toks: [2] * len(ids),
-                                  max_batch=2, metrics=reg)
+        sched = ContinuousBatcher(kv, prefill, dec.decode, max_batch=2,
+                                  release_fn=dec.free, metrics=reg)
         for r in range(3):
             sched.submit(Request(rid=r, prompt=[1, 2], max_new_tokens=2))
         done = sched.run()
@@ -620,9 +624,23 @@ class TestServingMetricsSmoke:
         dump = reg.to_dict()
         assert dump["serving_ttft_seconds"][0]["count"] == 3
         assert dump["serving_completed_total"][0]["value"] == 3.0
-        assert dump["serving_tick_seconds"][0]["count"] == \
+        # the decoder's own histogram times each tick; the scheduler's
+        # copy of it and the two gauges that restated the occupancy
+        # gauge are gone
+        assert dump["decoder_tick_seconds"][0]["count"] == \
             sched.stats.decode_steps
+        assert not {"serving_tick_seconds", "serving_active_sequences",
+                    "decoder_bucket_occupancy"} & set(dump)
         assert 0 < reg.gauge("serving_batch_occupancy").value <= 1.0
+        # each request's second token came from a decode tick
+        assert dump["serving_decoded_tokens_total"][0]["value"] == 3.0
+        assert dump["serving_queue_wait_seconds"][0]["count"] == 3
+        assert dump["serving_prefill_seconds"][0]["count"] == \
+            sched.stats.prefills == 3
+        for series in ("engine_dispatch_seconds", "engine_sync_seconds"):
+            counts = {e["labels"]["phase"]: e["count"] for e in dump[series]}
+            assert counts == {"prefill": sched.stats.prefills,
+                              "decode": sched.stats.decode_steps}
 
     def test_pager_metrics_mirror_stats(self, tmp_path):
         from repro.serving.pager import WeightPager

@@ -13,6 +13,8 @@ path is a hard failure, not a flake.
 
 import asyncio
 import contextlib
+import threading
+import time
 
 import pytest
 
@@ -386,3 +388,53 @@ class TestDebugEndpoints:
                 # the SLO histograms carry trace_id exemplars
                 assert 'serving_ttft_seconds_bucket' in text
                 assert '# {trace_id="' in text
+
+
+class TestQueueWait:
+    def test_wait_in_pending_counts_in_queue_wait_and_ttft(self, engine):
+        """A request admitted over HTTP just as a scheduler tick starts
+        waits in the server's pending queue through that whole tick.  Its
+        queue wait holds the tick, and so does its TTFT, which starts at
+        HTTP admission and not when the scheduler thread picks it up."""
+        with _server(engine, max_batch=2) as srv:
+            batcher = srv.batcher
+            decode, tick = batcher.decode_fn, batcher.tick
+            held = {}
+
+            def slow_decode(seq_ids, last_tokens):
+                time.sleep(0.3)
+                return decode(seq_ids, last_tokens)
+
+            def send_b():
+                held["resp"] = run(client.request(
+                    srv.cfg.host, srv.port, "POST", "/v1/completions",
+                    {"prompt": [7, 1], "max_tokens": 2}))
+
+            def tick_holding_b():
+                if held or not (batcher.queue or batcher.active):
+                    return tick()
+                held["sender"] = threading.Thread(target=send_b)
+                held["sender"].start()
+                deadline = time.monotonic() + 30.0
+                while not srv._pending:
+                    assert time.monotonic() < deadline
+                    time.sleep(0.001)
+                held["req"] = srv._pending[0]
+                t0 = time.perf_counter()
+                more = tick()
+                held["tick_s"] = time.perf_counter() - t0
+                return more
+
+            batcher.decode_fn, batcher.tick = slow_decode, tick_holding_b
+            resp_a = run(client.request(
+                srv.cfg.host, srv.port, "POST", "/v1/completions",
+                {"prompt": [3, 5, 8], "max_tokens": 3}))
+            held["sender"].join(timeout=60.0)
+            assert resp_a.status == 200 and held["resp"].status == 200
+            wait = srv.metrics.histogram("serving_queue_wait_seconds")
+            ttft = srv.metrics.histogram("serving_ttft_seconds")
+            assert wait.count == ttft.count == 2
+            assert held["tick_s"] >= 0.3
+            assert wait.percentile(100) >= held["tick_s"]
+            assert held["req"].first_token_s >= wait.percentile(100)
+            assert ttft.percentile(100) == held["req"].first_token_s
