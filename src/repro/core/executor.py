@@ -75,6 +75,21 @@ class DenseTable:
                          cols=tuple((c, self.col_types[c]) for c in self.cols))
 
 
+# A DenseTable is a pytree whose leaves are its columns, in stored order.
+# Keys, column order and column types ride in the static aux data, so a
+# table passed through ``jax.jit`` comes back with its columns in the
+# order it went in (a plain ``dict`` would be flattened in sorted-key
+# order).
+jax.tree_util.register_pytree_node(
+    DenseTable,
+    lambda t: (tuple(t.cols.values()),
+               (t.keys, tuple(t.cols), tuple(t.col_types.items()))),
+    lambda aux, leaves: DenseTable(keys=aux[0],
+                                   cols=dict(zip(aux[1], leaves)),
+                                   col_types=dict(aux[2])),
+)
+
+
 def table_from_chunked(ct) -> DenseTable:
     """Wrap a ChunkedTensor as a DenseTable (zero-copy)."""
     return DenseTable(
@@ -685,14 +700,17 @@ def execute(node: RelNode, env: Dict[str, DenseTable],
         t = env[node.table]
         s = node.table_schema
         if t.key_names != s.key_names or tuple(t.cols) != s.col_names:
-            # positional re-key: physical table layout matches, names differ
             if t.key_sizes != tuple(sz for _, sz in s.keys):
                 raise ValueError(
                     f"table {node.table!r}: stored key sizes {t.key_sizes} "
                     f"!= schema {s.keys}")
-            cols = dict(zip(s.col_names, t.cols.values()))
+            # the schema's columns stored in another order are matched by
+            # name; only a real rename (other names) pairs by position
+            stored = (s.col_names if set(t.cols) == set(s.col_names)
+                      else tuple(t.cols))
+            cols = {n: t.cols[o] for n, o in zip(s.col_names, stored)}
             col_types = {n: t.col_types[o]
-                         for n, o in zip(s.col_names, t.cols)}
+                         for n, o in zip(s.col_names, stored)}
             t = DenseTable(keys=s.keys, cols=cols, col_types=col_types)
         return t
     if id(node) in memo:

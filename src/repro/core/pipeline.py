@@ -1,8 +1,12 @@
 """Pipeline runner: executes a compiled relational pipeline on the JAX
 columnar engine (bind steps) with KV-cache INSERT semantics (append steps).
 
-``run_pipeline`` is functional in ``env``: cache tables are returned updated
-so the whole decode step can sit under ``jax.jit`` with donated buffers.
+``run_pipeline`` is functional in ``env``: cache tables are returned updated.
+The batched decode tick runs it under ``jax.jit``
+(``serving/engine.batched_decode_tick``, one program per batch bucket): the
+weight tables enter as arguments, never as constants, and the batch's cache
+views and the slot pool's tables are donated, so the tick updates the cache
+without a second copy.  Prefill and single-session decode run it eagerly.
 """
 
 from __future__ import annotations

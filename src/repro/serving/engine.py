@@ -33,6 +33,7 @@ from repro.core.opmap import op_map
 from repro.core.passes import postoptimize, preoptimize
 from repro.core.pipeline import run_pipeline
 from repro.obs.trace import traced
+from repro.serving.kvcache import appended_rows
 from repro.serving.pager import WeightPager
 
 # host-clock histograms of one served phase, labelled phase="prefill" or
@@ -42,6 +43,21 @@ PHASE_SERIES = {
     "engine_dispatch_seconds": "host time in one run_pipeline call",
     "engine_sync_seconds": "host wait for a plan's logits",
 }
+
+
+def batched_decode_tick(pipe, weights, views, tables, inputs, seq_ids,
+                        positions):
+    """One batched decode tick as a pure function, for ``jax.jit``: the
+    bucket's plan over the weight tables, the batch's cache views and the
+    tick's inputs (``token_ids``, ``freq_each_token``), then the row
+    writeback of :func:`~repro.serving.kvcache.appended_rows` into the
+    pool ``tables``.  Returns the logits column, the updated views and the
+    updated pool tables."""
+    env = {**weights, **views, **inputs}
+    outs, env = run_pipeline(pipe, env, scalars={"seq_positions": positions})
+    views = {name: env[name] for name in views}
+    return (outs["logits"].cols["v"], views,
+            appended_rows(tables, views, seq_ids, positions))
 
 
 @dataclasses.dataclass
@@ -237,6 +253,9 @@ class RelationalEngine:
         # replanning — only a tick whose bucket was never seen compiles a
         # new plan
         self._batched_pipes: Dict[tuple, object] = {}
+        # the compiled batched decode tick of each of those plans, under
+        # the same keys (see decode_path)
+        self._decode_ticks: Dict[tuple, object] = {}
         # paged residency: duplicate column copies compete with the working
         # set, so the global residency pass runs under the pager budget;
         # in-memory residency is unbounded.  One ResidencyPool is shared by
@@ -479,6 +498,35 @@ class RelationalEngine:
             self._batched_pipes[key] = pipe
         return self._batched_pipes[key]
 
+    @property
+    def decode_path(self) -> str:
+        """How a batched decode tick runs: ``"compiled"``, one XLA program
+        per batch bucket (:meth:`_decode_tick`), when the weights are held
+        in memory, no recorder is attached (it blocks on every step, which
+        a traced program cannot) and the plans are unsharded; otherwise
+        ``"eager"``, the plan dispatched op by op."""
+        if self.pager is None and self.tracer is None and self.shards == 1:
+            return "compiled"
+        return "eager"
+
+    def _decode_tick(self, batch: int, pipe):
+        """``(tick, weight names)``: the compiled tick over ``pipe``, the
+        bucket's registered batched plan, and the weight tables to pass it.
+        The weights enter as arguments (as closure constants they would be
+        embedded in the program; ``jit`` drops those the plan never reads).
+        Their names are those ``env_base`` held once the plan was
+        registered, so a table a later plan adds does not change the
+        tick's signature.  The views and pool tables are donated, so the
+        tick updates the cache without a second copy of it."""
+        key = (batch, self.shards)
+        if key not in self._decode_ticks:
+            def decode_tick(*args):  # names the program jit_decode_tick
+                return batched_decode_tick(pipe, *args)
+            self._decode_ticks[key] = (
+                jax.jit(decode_tick, donate_argnums=(1, 2)),
+                tuple(self.env_base))
+        return self._decode_ticks[key]
+
     @staticmethod
     def _decode_bucket(batch: int) -> int:
         """Batch-size bucket (next power of two) a tick's plan is keyed by."""
@@ -587,6 +635,7 @@ class RelationalEngine:
         # under the new weights (sessions join/leave freely meanwhile)
         self._prefill_pipes.clear()
         self._batched_pipes.clear()
+        self._decode_ticks.clear()
         self.replans += 1
         if self.metrics is not None:
             self.metrics.counter(
@@ -720,7 +769,10 @@ class BatchedDecoder:
       ``decode(seq_ids, last_tokens)``  — ONE ``run_pipeline`` call on the
                                           batch-bucketed seq-keyed plan;
                                           per-sequence positions ride in as
-                                          the ``seq_positions`` vector
+                                          the ``seq_positions`` vector.  It
+                                          runs as one compiled program with
+                                          the cache writeback inside where
+                                          ``engine.decode_path`` says so
 
     Ticks whose batch size is below the bucket pad by repeating the last
     sequence: the padded rows recompute that sequence's step and scatter
@@ -869,41 +921,61 @@ class BatchedDecoder:
         ids = list(seq_ids) + [seq_ids[-1]] * (bucket - B)
         toks = list(last_tokens) + [last_tokens[-1]] * (bucket - B)
         pipe = eng._batched_decode_pipe(bucket)
+        path = eng.decode_path
         positions = self.pool.positions[np.asarray(ids)]
         view_key = (tuple(ids), self.pool.slot_generations(ids))
         view_hit = self._view_key == view_key
         with traced("decode.views",
                     span("cache_views", cat="decoder",
                          outcome="hit" if view_hit else "miss")):
-            env = eng._weights_env()
-            if view_hit:
-                env.update(self._views)  # unchanged batch: reuse last views
-            else:
-                env.update(self.pool.gather_views(ids))
-            env["token_ids"] = lg.token_table(np.asarray(toks, np.int32),
-                                              key="seq")
-            env["freq_each_token"] = lg.rope_freq_table(
-                positions, eng.spec.head_dim, eng.spec.rope_theta,
-                key="seq")
+            # an unchanged batch reuses last tick's updated views
+            views = self._views if view_hit else self.pool.gather_views(ids)
+            inputs = {
+                "token_ids": lg.token_table(np.asarray(toks, np.int32),
+                                            key="seq"),
+                "freq_each_token": lg.rope_freq_table(
+                    positions, eng.spec.head_dim, eng.spec.rope_theta,
+                    key="seq")}
         if metrics is not None:
             metrics.counter("decoder_view_cache_total",
                             "batched cache-view gathers",
                             outcome="hit" if view_hit else "miss").inc()
+            metrics.counter("decoder_ticks_total",
+                            "batched decode ticks, by how they ran",
+                            path=path).inc()
         with traced("decode.dispatch",
                     hist=eng._phase_hist("engine_dispatch_seconds",
                                          "decode")):
-            outs, env = run_pipeline(
-                pipe, env,
-                scalars={"seq_positions": jnp.asarray(positions, jnp.int32)},
-                tracer=eng.tracer, shard_runner=eng._shard_runner)
+            if path == "compiled":
+                # the row writeback runs inside the compiled tick
+                tick, names = eng._decode_tick(bucket, pipe)
+                logits, views, tables = tick(
+                    {n: eng.env_base[n] for n in names}, views,
+                    self.pool.tables, inputs, np.asarray(ids, np.int32),
+                    positions)
+            else:
+                env = eng._weights_env()
+                env.update(views)
+                env.update(inputs)
+                outs, env = run_pipeline(
+                    pipe, env,
+                    scalars={"seq_positions": jnp.asarray(positions,
+                                                          jnp.int32)},
+                    tracer=eng.tracer, shard_runner=eng._shard_runner)
+                logits = outs["logits"].cols["v"]
+                views = {name: env[name] for name in self.pool.tables}
         self.decode_calls += 1
         # the tick's only cache mutation is one appended row per sequence
         # at positions[b] — write back just those rows; the updated views
         # (which already contain them) serve the next tick's gather
         with traced("decode.writeback", span("cache_writeback",
                                              cat="decoder")):
-            self.pool.scatter_rows(ids, env, positions)
-            self._views = {name: env[name] for name in self.pool.tables}
+            if path == "compiled":
+                for name, t in tables.items():
+                    self.pool.tables[name].cols.update(t.cols)
+            else:
+                self.pool.scatter_rows(ids, views, positions)
+            self._views = views
             self._view_key = view_key
             for s in seq_ids:
                 self.pool.positions[s] += 1
@@ -911,7 +983,7 @@ class BatchedDecoder:
             with traced("decode.sync",
                         hist=eng._phase_hist("engine_sync_seconds",
                                              "decode")):
-                logits = np.asarray(outs["logits"].cols["v"])
+                logits = np.asarray(logits)
             logits = logits.reshape(bucket, -1)[:B, : eng.spec.vocab]
             next_toks = [int(t) for t in np.argmax(logits, axis=1)]
         self.last_logits = logits

@@ -497,10 +497,12 @@ class BatchedCacheTables:
         ids = np.asarray(seq_ids, np.int32)
         bound = [(b, int(s)) for b, s in enumerate(ids)
                  if int(s) in self.bindings]
+        gathered = _slot_rows({name: next(iter(pool.cols.values()))
+                               for name, pool in self.tables.items()}, ids)
         out = {}
         for name, pool in self.tables.items():
             cn = next(iter(pool.cols))
-            arr = pool.cols[cn][ids]
+            arr = gathered[name]
             pax = pool.key_names.index(pos_key) - 1  # axis within a slot
             for b, sid in bound:
                 seg, boundary = self.bindings[sid]
@@ -542,14 +544,36 @@ class BatchedCacheTables:
         position axis.  Duplicate ids (bucket padding) write identical
         values.
         """
-        ids = jnp.asarray(seq_ids, jnp.int32)
-        pos = jnp.asarray(positions, jnp.int32)
-        b_idx = jnp.arange(len(seq_ids))
+        new = appended_rows(self.tables, env, seq_ids, positions, pos_key)
         for name, pool in self.tables.items():
-            cn = next(iter(pool.cols))
-            pax = pool.key_names.index(pos_key)  # seq is axis 0
-            upd = env[name].cols[cn].astype(pool.cols[cn].dtype)
-            rows = jnp.moveaxis(upd, pax, 1)[b_idx, pos]
-            p2 = jnp.moveaxis(pool.cols[cn], pax, 1)
-            p2 = p2.at[ids, pos].set(rows)
-            pool.cols[cn] = jnp.moveaxis(p2, 1, pax)
+            pool.cols.update(new[name].cols)
+
+
+@jax.jit
+def _slot_rows(cols, ids):
+    """Each pool column's slots ``ids``: one program per batch size, where
+    indexing the tables eagerly launches several programs per table."""
+    return {name: arr[ids] for name, arr in cols.items()}
+
+
+def appended_rows(tables, views, seq_ids, positions, pos_key: str = "tp"):
+    """The pool ``tables`` with each sequence's row at ``(seq_ids[b],
+    positions[b])`` taken from batch row ``b`` of the updated ``views``:
+    a pure function, so it runs eagerly (:meth:`BatchedCacheTables.
+    scatter_rows`) and inside the compiled decode tick alike."""
+    from repro.core.executor import DenseTable
+    ids = jnp.asarray(seq_ids, jnp.int32)
+    pos = jnp.asarray(positions, jnp.int32)
+    b_idx = jnp.arange(ids.shape[0])
+    out = {}
+    for name, pool in tables.items():
+        cn = next(iter(pool.cols))
+        pax = pool.key_names.index(pos_key)  # seq is axis 0
+        upd = views[name].cols[cn].astype(pool.cols[cn].dtype)
+        rows = jnp.moveaxis(upd, pax, 1)[b_idx, pos]
+        p2 = jnp.moveaxis(pool.cols[cn], pax, 1)
+        p2 = p2.at[ids, pos].set(rows)
+        out[name] = DenseTable(keys=pool.keys,
+                               cols={cn: jnp.moveaxis(p2, 1, pax)},
+                               col_types=pool.col_types)
+    return out

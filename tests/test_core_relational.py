@@ -150,6 +150,48 @@ class TestExecutor:
         np.testing.assert_array_equal(arr, np.eye(3, 8)[[2, 0, 1]])
 
 
+    def test_scan_matches_stored_columns_by_name(self):
+        """A table whose columns are stored in another order than its
+        schema's (as a dict flattened in sorted-key order under ``jit``
+        rebuilds them) is read by name; only a real rename pairs stored
+        columns with the schema's by position."""
+        fr = jnp.asarray([[1.0, 2.0]])
+        fi = jnp.asarray([[3.0, 4.0]])
+        schema = DenseTable(keys=(("t", 1),), cols={"fr": fr, "fi": fi},
+                            col_types={"fr": VEC(2), "fi": VEC(2)}).schema()
+        stored = DenseTable(keys=(("t", 1),), cols={"fi": fi, "fr": fr},
+                            col_types={"fi": VEC(2), "fr": VEC(2)})
+        out = execute(Scan("freq", schema), {"freq": stored})
+        assert tuple(out.cols) == ("fr", "fi")
+        np.testing.assert_array_equal(np.asarray(out.cols["fr"]), fr)
+        np.testing.assert_array_equal(np.asarray(out.cols["fi"]), fi)
+        renamed = DenseTable(keys=(("u", 1),), cols={"a": fr, "b": fi},
+                             col_types={"a": VEC(2), "b": SCALAR})
+        out = execute(Scan("freq", schema), {"freq": renamed})
+        assert out.key_names == ("t",)
+        np.testing.assert_array_equal(np.asarray(out.cols["fr"]), fr)
+        np.testing.assert_array_equal(np.asarray(out.cols["fi"]), fi)
+        assert out.col_types == {"fr": VEC(2), "fi": SCALAR}
+
+    def test_dense_table_keeps_column_order_through_jit(self):
+        """A DenseTable is a pytree: through ``jax.jit`` it keeps its keys,
+        its column order and its column types."""
+        import jax
+        t = DenseTable(keys=(("t", 2),),
+                       cols={"fr": jnp.ones((2, 3)), "fi": jnp.zeros((2, 3))},
+                       col_types={"fr": VEC(3), "fi": VEC(3)})
+        out = jax.jit(lambda x: x)(t)
+        assert isinstance(out, DenseTable)
+        assert out.keys == t.keys and tuple(out.cols) == ("fr", "fi")
+        assert out.col_types == t.col_types
+        np.testing.assert_array_equal(np.asarray(out.cols["fr"]), 1.0)
+        np.testing.assert_array_equal(np.asarray(out.cols["fi"]), 0.0)
+        doubled = jax.jit(lambda x: jax.tree_util.tree_map(
+            lambda a: a * 2 + 1, x))(t)
+        assert tuple(doubled.cols) == ("fr", "fi")
+        np.testing.assert_array_equal(np.asarray(doubled.cols["fi"]), 1.0)
+
+
 class TestPasses:
     def _proj_chain(self):
         t = DenseTable(keys=(("i", 4),), cols={"v": jnp.arange(4.0)},

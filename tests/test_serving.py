@@ -690,3 +690,149 @@ class TestPrefixCachedDecode:
         tok, cached = dec.prefill_ex(self.PREFIX + [1], 0)
         assert cached == 0
         assert self._decode_n(dec, 0, tok, 3) == ref
+
+
+class TestCompiledDecodeTick:
+    """An in-memory engine runs each batched decode tick as one compiled
+    program per batch bucket, with the cache writeback inside it; paged,
+    traced and sharded engines dispatch the plan eagerly.  Both give the
+    same logits."""
+
+    PREFIX = [5, 9, 2, 7, 11, 4, 6, 8]   # two full hash blocks (block=4)
+
+    @pytest.fixture(scope="class")
+    def params(self):
+        from repro.core.llama_graph import init_llama_params
+        return init_llama_params(self._spec(), seed=3)
+
+    @staticmethod
+    def _spec():
+        from repro.core.llama_graph import LlamaSpec
+        return LlamaSpec(vocab=64, d_model=32, n_layers=1, n_heads=4,
+                         n_kv=2, d_ff=64, rope_theta=10000.0)
+
+    def _engine(self, params, kind, metrics=None):
+        from repro.obs import TraceRecorder
+        from repro.serving.engine import RelationalEngine
+        kw = {"paged": dict(residency="paged", budget_bytes=1 << 22),
+              "traced": dict(tracer=TraceRecorder()),
+              "sharded": dict(shards=2)}.get(kind, {})
+        return RelationalEngine(self._spec(), params, chunk_size=8,
+                                max_len=24, metrics=metrics, **kw)
+
+    def _serve_script(self, eng):
+        """Ticks over 4 slots, one of them bound to a shared prefix
+        segment: the full batch twice (the second reuses its views), a
+        smaller bucket, a bucket of one, then a slot reused by a new
+        sequence in a padded bucket.  Returns each tick's logits."""
+        dec = eng.batched_decoder(max_seqs=4, prefix_block=4,
+                                  prefix_bind="share")
+        prompts = [self.PREFIX + [1], [3, 4, 5], self.PREFIX + [2],
+                   [7, 1, 4, 2, 9]]
+        tok = {}
+        for s, p in enumerate(prompts):
+            tok[s], cached = dec.prefill_ex(p, s)
+            assert cached == (len(self.PREFIX) if s == 2 else 0)
+        assert 2 in dec.pool.bindings   # the share-bound slot
+        got = []
+
+        def tick(ids):
+            out = dec.decode(ids, [tok[s] for s in ids])
+            tok.update(zip(ids, out))
+            got.append((ids, dec.last_logits))
+
+        for ids in ([0, 1, 2, 3], [0, 1, 2, 3], [0, 2], [3]):
+            tick(ids)
+        dec.free(1)
+        tok[1], _ = dec.prefill_ex([6, 6, 2, 8], 1)  # a new prefill length
+        for ids in ([0, 1, 2], [0, 1, 2, 3]):
+            tick(ids)
+        return got
+
+    def test_compiled_tick_matches_eager(self, params):
+        """The compiled tick and the eager plan give the same logits, tick
+        by tick, across a view reuse, bucket changes, a reused slot and a
+        share-bound slot."""
+        compiled = self._engine(params, "in_memory")
+        eager = self._engine(params, "traced")
+        assert (compiled.decode_path, eager.decode_path) == \
+            ("compiled", "eager")
+        for (ids, c), (_, e) in zip(self._serve_script(compiled),
+                                    self._serve_script(eager)):
+            assert c.shape == e.shape == (len(ids), 64)
+            np.testing.assert_allclose(c, e, rtol=0, atol=1e-5)
+            np.testing.assert_array_equal(c.argmax(1), e.argmax(1))
+
+    @pytest.mark.parametrize("boundary", ["tables", "columns"])
+    def test_jitted_batched_plan_matches_eager(self, params, boundary):
+        """``run_pipeline`` over the batched plan under ``jax.jit`` gives
+        the eager logits at 4 sequences.  Passed as plain column dicts
+        (``columns``), the tables come back inside the jitted function
+        with their columns in sorted-key order: the RoPE frequency table
+        as ``(fi, fr)``, which the Scan has to read by name."""
+        import jax
+        from repro.core import llama_graph as lg
+        from repro.core.executor import DenseTable
+        from repro.core.pipeline import run_pipeline
+        eng = self._engine(params, "in_memory")
+        dec = eng.batched_decoder(max_seqs=4, prefix_block=0)
+        prompts = [[5, 9, 2, 7], [1, 2, 3], [11, 4, 6, 8, 10], [3, 3]]
+        toks = [dec.prefill(p, s) for s, p in enumerate(prompts)]
+        positions = dec.pool.positions[:4].copy()
+        env = eng._weights_env()
+        env.update(dec.pool.gather_views([0, 1, 2, 3]))
+        env["token_ids"] = lg.token_table(np.asarray(toks, np.int32),
+                                          key="seq")
+        env["freq_each_token"] = lg.rope_freq_table(
+            positions, eng.spec.head_dim, eng.spec.rope_theta, key="seq")
+        assert tuple(env["freq_each_token"].cols) == ("fr", "fi")
+        pipe = eng._batched_decode_pipe(4)
+
+        def logits(env, pos):
+            outs, _ = run_pipeline(pipe, env, {"seq_positions": pos})
+            return outs["logits"].cols["v"].reshape(4, -1)[:, :64]
+
+        pos = jnp.asarray(positions, jnp.int32)
+        want = np.asarray(logits(env, pos))
+        if boundary == "tables":
+            got = jax.jit(logits)(env, pos)
+        else:
+            def from_columns(cols, pos):
+                return logits({n: DenseTable(keys=env[n].keys, cols=c,
+                                             col_types=env[n].col_types)
+                               for n, c in cols.items()}, pos)
+            got = jax.jit(from_columns)(
+                {n: t.cols for n, t in env.items()}, pos)
+        np.testing.assert_allclose(np.asarray(got), want, rtol=0, atol=1e-5)
+        np.testing.assert_array_equal(np.asarray(got).argmax(1),
+                                      want.argmax(1))
+
+    @pytest.mark.parametrize("kind,path", [("in_memory", "compiled"),
+                                           ("paged", "eager"),
+                                           ("traced", "eager"),
+                                           ("sharded", "eager")])
+    def test_decode_path_is_counted(self, params, kind, path):
+        """``decoder_ticks_total`` counts each tick under the path it took;
+        the compiled path compiles one program per bucket, however many
+        ticks and view changes run in it."""
+        from repro.obs import MetricsRegistry
+        reg = MetricsRegistry()
+        eng = self._engine(params, kind, metrics=reg)
+        try:
+            ticks = self._serve_script(eng)
+        finally:
+            if eng.shard_pool is not None:
+                eng.shard_pool.shutdown()
+        counted = {e["labels"]["path"]: e["value"]
+                   for e in reg.to_dict()["decoder_ticks_total"]}
+        assert counted == {path: len(ticks)}
+        if path == "compiled":
+            assert set(eng._decode_ticks) == {(4, 1), (2, 1), (1, 1)}
+            # a table that a later plan adds to the weights leaves the
+            # compiled ticks as they are
+            eng.env_base["added_by_a_later_plan"] = eng.env_base["Final_Norm"]
+            self._serve_script(eng)
+            assert all(tick._cache_size() == 1
+                       for tick, _ in eng._decode_ticks.values())
+        else:
+            assert not eng._decode_ticks

@@ -156,6 +156,13 @@ def test_served_spans_nest_on_the_profiler_clock(tmp_path):
         max_pages_per_seq=6), max_seqs=4)
     srv = AsyncLLMServer(engine, kv, ServerConfig(port=0, max_batch=2),
                          metrics=MetricsRegistry())
+    # compile the decode tick of both batch buckets before the capture
+    dec = srv.decoder
+    toks = [dec.prefill([1, 2, 3], s) for s in range(2)]
+    dec.decode([0], toks[:1])
+    dec.decode([0, 1], toks)
+    for s in range(2):
+        dec.free(s)
     srv.start_in_thread()
 
     async def complete(n):
@@ -194,10 +201,12 @@ def test_served_spans_nest_on_the_profiler_clock(tmp_path):
     assert len(named("sched.decode")) == len(named("sched.emit")) == ticks
     assert ticks >= 2 and named("sched.wait")
     assert {ev[3]["rid"] for ev in named("sched.prefill")} == {2, 3}
-    n_steps = {
-        "prefill": len(engine._prefill_pipe(3).steps),
-        "decode": len(engine._batched_decode_pipe(
-            engine._decode_bucket(2)).steps)}
+    # an in-memory engine runs each decode tick as one compiled program,
+    # whose steps annotate the host only while it is traced (the HLO names
+    # them, test_jitted_pipeline_names_each_step_in_its_hlo); prefill is
+    # dispatched step by step
+    assert engine.decode_path == "compiled"
+    n_steps = {"prefill": len(engine._prefill_pipe(3).steps), "decode": 0}
 
     def one_each(parent, names):
         kids = [_inside(sched, parent, n) for n in names]
