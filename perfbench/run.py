@@ -24,9 +24,10 @@ repeat the checks.
 
 Without a TPU, or with fewer chips than the cell asks for, it prints no
 result and exits 3.  ``--rehearse`` runs the same path on whatever JAX
-finds, with the tiny configuration and the tiny mix of the cell's traffic
-kind (``configs/tiny.json``, ``traffic/rehearsal-<kind>.json``), and marks
-its line ``"rehearsal": true``.
+finds, with the tiny configuration of the cell's architecture
+(``configs/tiny*.json``) and the tiny mix of its traffic kind
+(``traffic/rehearsal-<kind>.json``), and marks its line
+``"rehearsal": true``.
 """
 
 from __future__ import annotations
@@ -182,17 +183,18 @@ def note(msg: str) -> None:
 
 
 def reference_model(cell):
-    name = cell.config["reference"]
-    return spec.load_module(spec.HERE / "models" / f"{name}.py",
-                            f"perfbench_model_{name}")
+    return spec.architecture("models", cell.config["reference"])
 
 
 def build(cell, model, seed, compiles):
-    """Weights drawn on the device, the server built and warmed."""
+    """Weights drawn on the device, the server built and warmed.  A
+    configuration the program does not build fails first, before a weight
+    is drawn."""
     import jax
     import numpy as np
     from repro.obs.metrics import MetricsRegistry
     cfg = cell.config
+    model_spec = serve.program_spec(cfg)
     t = time.monotonic()
     params = {k: np.asarray(v) for k, v in
               jax.device_get(model.make_weights(cfg, seed)).items()}
@@ -200,7 +202,7 @@ def build(cell, model, seed, compiles):
          f"the device in {time.monotonic() - t:.1f} s")
     t = time.monotonic()
     metrics = MetricsRegistry()
-    server = serve.build(cfg, cfg["serve"], params, metrics)
+    server = serve.build(cfg, model_spec, params, metrics)
     del params
     note(f"server built in {time.monotonic() - t:.1f} s; device memory "
          f"{jax.devices()[0].memory_stats()}")

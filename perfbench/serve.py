@@ -1,10 +1,10 @@
 """Build the system under test and record what its callbacks do.
 
 The server is built through ``repro.serving.boot.build_server``, the
-construction path of the repository's HTTP entry point, from a
-configuration file's sizes and weights drawn by the benchmark.  The
-benchmark then wraps the scheduler's two callbacks on the instance (no
-program file is edited):
+construction path of the repository's HTTP entry point, from the program's
+spec of the configuration (``programs/<reference>.py``) and weights drawn
+by the benchmark.  The benchmark then wraps the scheduler's two callbacks
+on the instance (no program file is edited):
 
 * ``perfbench.prefill`` and ``perfbench.decode`` profiler annotations
   around them, so that a trace places device activity and idle gaps
@@ -26,6 +26,8 @@ from typing import Dict, List, Tuple
 
 import numpy as np
 
+from perfbench import spec
+
 PREFILL = "perfbench.prefill"
 DECODE = "perfbench.decode"
 KEY_TOKENS = 32  # a prompt's first tokens identify its request
@@ -35,31 +37,27 @@ def prompt_key(prompt) -> Tuple[int, ...]:
     return tuple(int(t) for t in prompt[:KEY_TOKENS])
 
 
-def llama_spec(cfg: dict):
-    from repro.core.llama_graph import LlamaSpec
-    from perfbench.models.dense_gqa import dims
-    m = dims(cfg)
-    if m["dh"] * m["h"] != m["d"]:
-        raise ValueError("the relational path derives head_dim as "
-                         "hidden_size / num_attention_heads")
-    return LlamaSpec(vocab=m["vocab"], d_model=m["d"], n_layers=m["layers"],
-                     n_heads=m["h"], n_kv=m["kv"], d_ff=m["ff"],
-                     rope_theta=m["theta"], eps=m["eps"])
+def program_spec(cfg: dict):
+    """The program's spec of the configuration's architecture; raises for
+    a ``model_type`` the program does not build."""
+    return spec.architecture("programs", cfg["reference"]).spec_from_config(
+        cfg)
 
 
-def build(cfg: dict, serve: dict, params: Dict[str, np.ndarray], metrics):
+def build(cfg: dict, model_spec, params: Dict[str, np.ndarray], metrics):
     """An ``AsyncLLMServer`` with pages for every slot's whole context, so
     that no request is preempted (a resumed request would prefill a length
     that was never warmed)."""
     from repro.serving.boot import PAGE_SIZE, build_server
     from repro.serving.server import ServerConfig
+    serve = cfg["serve"]
     max_len = int(serve["max_len"])
     sc = ServerConfig(port=0, model_id=cfg["name"],
                       max_batch=int(serve["max_batch"]),
                       max_tokens_cap=int(serve["max_tokens_cap"]),
                       max_queue_depth=int(serve.get("max_queue_depth", 32)))
     n_pages = sc.max_batch * -(-max_len // PAGE_SIZE)
-    return build_server(llama_spec(cfg), params, sc, max_len=max_len,
+    return build_server(model_spec, params, sc, max_len=max_len,
                         n_pages=n_pages, metrics=metrics)
 
 

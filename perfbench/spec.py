@@ -7,11 +7,31 @@ the end-to-end and per-layer metrics that list it.  A metric's reader is
 one reader serves several metrics (``decode_tick_ms.batch`` and a later
 mix's ``decode_tick_ms.<mix>``).  Adding a cell, a mix or a metric adds files
 and entries; nothing here names one.
+
+Nor does anything here, in ``serve.py``, ``flops.py`` or ``run.py``, name a
+model architecture.  A configuration's ``reference`` key names its
+architecture, and a new one adds:
+
+* a configuration file with the published ``model_type`` and the
+  ``reference``;
+* ``models/<reference>.py``: the plain reference and its weights
+  (``make_weights``, ``logits_at``);
+* ``counts/<reference>.py``: the operations and bytes a step needs
+  (``decode_tick``, ``decode_token_flops``, ``prefill_flops``);
+* ``programs/<reference>.py``: ``spec_from_config``, the program's spec
+  of the architecture read from the configuration's keys, with a branch
+  for each ``model_type`` of it that the program builds;
+* ``configs/tiny-<reference>.json``: a toy of the architecture for the CPU
+  rehearsal (``configs/tiny.json`` is the one of ``dense_gqa``).
+
+A part that is missing is an error naming it, never another
+architecture's.
 """
 
 from __future__ import annotations
 
 import dataclasses
+import functools
 import importlib.util
 import json
 from pathlib import Path
@@ -63,6 +83,29 @@ def reader(name: str) -> Callable:
                             f"{name!r}")
 
 
+@functools.lru_cache(maxsize=None)
+def architecture(part: str, reference: str):
+    """The module ``<part>/<reference>.py`` of one architecture: ``models``,
+    ``counts`` or ``programs``."""
+    path = HERE / part / f"{reference}.py"
+    if not path.is_file():
+        raise FileNotFoundError(f"no {part}/{reference}.py for the "
+                                f"architecture {reference!r}")
+    return load_module(path, f"perfbench_{part}_{reference}")
+
+
+def rehearsal_config(reference: str, where: Path = HERE / "configs") -> dict:
+    """The one tiny configuration (``tiny.json`` or ``tiny-*.json``) whose
+    ``reference`` is ``reference``."""
+    paths = [*where.glob("tiny.json"), *sorted(where.glob("tiny-*.json"))]
+    found = [c for c in map(load_json, paths) if c["reference"] == reference]
+    if len(found) != 1:
+        raise LookupError(f"{len(found)} tiny configurations of the "
+                          f"architecture {reference!r} in {where}; the "
+                          f"rehearsal needs exactly one")
+    return found[0]
+
+
 def generator(kind: str):
     """The traffic generator module of a mix's ``kind``."""
     return load_module(HERE / "gen" / f"{kind}.py", f"perfbench_gen_{kind}")
@@ -85,8 +128,8 @@ def _metrics(entries: List[dict], cell: str) -> List[Metric]:
 def load_cell(name: str, rehearse: bool = False,
               bench_path: Path = ROOT / "BENCHMARK.json") -> Cell:
     """The cell ``name`` of ``BENCHMARK.json``.  ``rehearse`` keeps its
-    metrics and traffic kind but swaps in the tiny configuration and the
-    tiny mix of that kind, for a run on the CPU."""
+    metrics and traffic kind but swaps in the tiny configuration of its
+    architecture and the tiny mix of that kind, for a run on the CPU."""
     bench = load_json(bench_path)
     cells = {w["name"]: w for w in bench["workloads"]}
     if name not in cells:
@@ -98,7 +141,7 @@ def load_cell(name: str, rehearse: bool = False,
     traffic_name = w["traffic"]
     traffic = load_json(traffic_path(traffic_name))
     if rehearse:
-        config = load_json(HERE / "configs" / "tiny.json")
+        config = rehearsal_config(config["reference"])
         traffic_name = f"rehearsal-{traffic['kind']}"
         traffic = load_json(traffic_path(traffic_name))
     return Cell(name=name, chips=int(w["chips"]), config=config,

@@ -22,6 +22,7 @@ from perfbench.record import Run  # noqa: E402
 from perfbench.serve import DECODE, PREFILL  # noqa: E402
 
 V5E = spec.peaks("TPU v5 lite")
+DENSE = spec.architecture("counts", "dense_gqa")
 
 
 def config(name, **changes):
@@ -188,7 +189,7 @@ def test_hbm_peak():
 ])
 def test_matrix_params_by_hand(name, layers, params):
     cfg = config(name, num_hidden_layers=layers)
-    assert flops.matrix_params(cfg) == params
+    assert DENSE.matrix_params(cfg) == params
 
 
 def test_configured_depths():
@@ -199,14 +200,14 @@ def test_configured_depths():
 def test_decode_tick_counts_by_hand():
     cfg = config("mistral-7b")
     # 6 layers: 32 heads of 128; KV per token 2 * 6 * 8 * 128 * 4 bytes
-    assert flops.kv_bytes_per_token(cfg) == 49_152
+    assert DENSE.kv_bytes_per_token(cfg) == 49_152
     f, b = flops.decode_tick(cfg, 8, 1000)
     assert f == 2 * 8 * 1_442_840_576 + 4 * 6 * 32 * 128 * 1000
     assert b == 1_442_840_576 * 4 + 8 * 4096 * 4 + 49_152 * 1000
     assert 5.77e9 < 1_442_840_576 * 4 < 5.78e9
     ds = config("deepseek-7b", num_hidden_layers=3)
-    assert flops.kv_bytes_per_token(ds) == 2 * 3 * 32 * 128 * 4
-    assert 4.10e9 < flops.matrix_params(ds) * 4 < 4.11e9
+    assert DENSE.kv_bytes_per_token(ds) == 2 * 3 * 32 * 128 * 4
+    assert 4.10e9 < DENSE.matrix_params(ds) * 4 < 4.11e9
     # at these batches the tick is bounded by bytes, not operations
     t = flops.least_time_s(f, b, V5E)
     assert t == pytest.approx(b / V5E["hbm_bytes_per_s"])
@@ -219,6 +220,14 @@ def test_prefill_flops_by_hand():
     assert flops.prefill_flops(cfg, n) == (
         2 * n * 2 * layer + 4 * 2 * 32 * 128 * n * (n + 1) // 2
         + 2 * 102400 * 4096)
+
+
+def test_counts_of_an_architecture_without_a_counts_module_are_an_error():
+    cfg = config("mistral-7b", reference="no_such_architecture")
+    with pytest.raises(FileNotFoundError, match="counts/no_such_arch"):
+        flops.decode_tick(cfg, 8, 1000)
+    with pytest.raises(FileNotFoundError, match="no_such_architecture"):
+        flops.prefill_flops(cfg, 128)
 
 
 def test_unknown_device_kind_is_an_error():

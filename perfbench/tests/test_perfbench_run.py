@@ -18,7 +18,7 @@ for p in (str(ROOT), str(ROOT / "src")):
     if p not in sys.path:
         sys.path.insert(0, p)
 
-from perfbench import correct, spec  # noqa: E402
+from perfbench import correct, flops, serve, spec  # noqa: E402
 
 RUN = ROOT / "perfbench" / "run.py"
 KEYS = {"correct", "attempted", "failed", "metrics", "device"}
@@ -69,6 +69,17 @@ def test_every_cell_finds_its_files():
         for m in cell.per_layer:
             assert moved[m.name] in reported
         assert spec.generator(cell.traffic["kind"]).run
+        # the architecture's parts, each found by the configuration's
+        # reference: the program's spec, the counts, the reference, the
+        # rehearsal's tiny configuration
+        ref = cell.config["reference"]
+        assert serve.program_spec(cell.config).n_layers == \
+            cell.config["num_hidden_layers"]
+        assert flops.counts(cell.config).decode_tick
+        assert spec.architecture("models", ref).make_weights
+        tiny = spec.rehearsal_config(ref)
+        assert tiny["reference"] == ref
+        assert spec.load_cell(w["name"], rehearse=True).config == tiny
 
 
 def check_line(line, cell, trace):
