@@ -2,12 +2,16 @@
 
     PYTHONPATH=src python examples/serve_http.py [--port 8008]
         [--max-batch 3] [--paged] [--n-pages 48] [--max-queue 32]
-        [--model tiny|<registry name>] [--layers N] [--max-len 96]
+        [--model tiny|<registry name>|<config.json>] [--layers N]
+        [--max-len 96]
 
 The default is the tiny model.  ``--model llama3-8b --layers 4`` serves
 the registered Llama-3-8B at its published widths with the depth cut to 4
 layers (random weights from seed 0); such a model wants a TPU, a larger
-``--max-len``/``--n-pages`` and a larger ``--max-tokens-cap``.  JAX's
+``--max-len``/``--n-pages`` and a larger ``--max-tokens-cap``.  The path
+of a published ``config.json`` serves that model, read by its
+``model_type`` (``perfbench/configs/deepseek-moe-16b.json`` is
+DeepSeekMoE-16B with 16 of its 64 experts held).  JAX's
 compile cache goes to ``$JAX_COMPILATION_CACHE_DIR``, else ``.jax_cache/``
 in the checkout.
 
@@ -119,10 +123,10 @@ def main():
     ap.add_argument("--host", default="127.0.0.1")
     ap.add_argument("--port", type=int, default=8008)
     ap.add_argument("--model", default="tiny",
-                    help="'tiny' or a registered dense Llama config "
-                         "(e.g. llama3-8b)")
+                    help="'tiny', a registered dense Llama config "
+                         "(e.g. llama3-8b) or a published config.json")
     ap.add_argument("--layers", type=int, default=None,
-                    help="cut a registered model's depth to this many "
+                    help="cut the model's depth to this many "
                          "layers (widths stay as published)")
     ap.add_argument("--max-len", type=int, default=96,
                     help="context cap: prompt + generated tokens")

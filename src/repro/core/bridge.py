@@ -4,7 +4,9 @@ production model stack's parameter tree (dense Llama family only).
 Used by the equivalence tests and the quickstart example to prove the two
 execution paths (relational pipelines vs direct JAX) implement the same
 model, weight-for-weight, and by the serving entry points to build the
-relational path from a registered config.
+relational path from a registered dense config.  Sparse-expert models
+(DeepSeekMoE) are read from their published ``config.json`` by
+``repro.serving.boot.spec_from_config``, not from the registry.
 """
 
 from __future__ import annotations
@@ -37,8 +39,11 @@ def config_to_spec(cfg: ModelConfig, n_layers: Optional[int] = None
                               "capacity_factor")]
     differ = [f for f in arch if getattr(cfg, f) != getattr(plain, f)]
     if differ:
-        raise ValueError(f"{cfg.name}: the relational path builds only the "
-                         f"dense GQA Llama family; {differ} differ from it")
+        raise ValueError(f"{cfg.name}: a registered config builds only the "
+                         f"dense GQA Llama family; {differ} differ from it "
+                         f"(sparse-expert models are read from their "
+                         f"published config.json by "
+                         f"repro.serving.boot.spec_from_config)")
     n = cfg.n_layers if n_layers is None else n_layers
     if not 1 <= n <= cfg.n_layers:
         raise ValueError(f"{cfg.name}: n_layers {n} outside [1, "

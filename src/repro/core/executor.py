@@ -10,6 +10,7 @@ whose physical operators lower to XLA:
   GroupAgg       → axis reduction
   Filter         → predicate mask (identity element supplied by the plan)
   Unnest/Collect → reshapes between key axes and the vector payload axis
+  TopK           → ``jax.lax.top_k`` along the ordered key
 
 Physical optimisation (the "query optimiser"): a ``GroupAgg(Join(L, R))``
 whose aggregate is ``SUM`` of a product/dot of one column from each side is
@@ -17,7 +18,11 @@ executed as a fused contraction (``jnp.einsum``) — the relational join never
 materialises, mirroring how a vectorised DB pipelines a hash join into an
 aggregation without materialising the cross product.  On TPU this is the
 MatMul-goes-to-MXU path; ``kernels/chunked_matmul`` is the hand-scheduled
-version of the same plan.
+version of the same plan.  The same aggregate over a *value* join on the
+weight table's leading key (a routed-expert join: each row names the
+expert it is routed to) runs as a grouped matmul: rows sorted by that key,
+each group against its own slice of the table, rows that name no slice
+dropped (:func:`grouped_matmul`).
 """
 
 from __future__ import annotations
@@ -33,7 +38,7 @@ import numpy as np
 from repro.core import relational as ra
 from repro.core.relational import (
     BinOp, Call, Col, Collect, Const, Expr, Filter, GroupAgg, Join, Key,
-    KeyParam, Param, Project, RelNode, RelSchema, Scan, Unnest, SCALAR,
+    KeyParam, Param, Project, RelNode, RelSchema, Scan, TopK, Unnest, SCALAR,
     is_vec, resolve,
 )
 
@@ -272,6 +277,7 @@ OP_CLASSES = {
     Filter: "filter",
     Unnest: "unnest",
     Collect: "collect",
+    TopK: "sort",
 }
 
 
@@ -288,7 +294,8 @@ def iter_plan_nodes(root: RelNode):
     while stack:
         node = stack.pop()
         yield node
-        if isinstance(node, (Project, Filter, Unnest, Collect, GroupAgg)):
+        if isinstance(node, (Project, Filter, Unnest, Collect, GroupAgg,
+                             TopK)):
             kids = (node.input,)
         elif isinstance(node, Join):
             kids = (node.left, node.right)
@@ -326,9 +333,10 @@ def _key_axis(table: DenseTable, name: str) -> int:
     return table.key_names.index(name)
 
 
-def _eval_key_expr(expr: Expr, key_names, key_sizes, scalars=None
-                   ) -> jnp.ndarray:
-    """Evaluate an integer expression over key columns.
+def _eval_key_expr(expr: Expr, key_names, key_sizes, scalars=None,
+                   table: Optional[DenseTable] = None) -> jnp.ndarray:
+    """Evaluate an integer expression over key columns (and over the
+    scalar columns of ``table``, where one is given).
 
     Returns an array broadcastable against ``[*key_sizes]`` (aranges are
     reshaped into their key's axis position, so e.g. ``h // 4`` stays O(H)).
@@ -342,6 +350,8 @@ def _eval_key_expr(expr: Expr, key_names, key_sizes, scalars=None
             shape = [1] * nk
             shape[ax] = key_sizes[ax]
             return jnp.arange(key_sizes[ax], dtype=jnp.int32).reshape(shape)
+        if isinstance(e, Col) and table is not None:
+            return table.cols[e.name]
         if isinstance(e, Const):
             return jnp.asarray(int(e.value), dtype=jnp.int32)
         if isinstance(e, Param):
@@ -670,6 +680,137 @@ def _try_fused_join_agg(node: GroupAgg, env, memo, scalars=None,
     )
 
 
+def grouped_block(rows: int, groups: int) -> int:
+    """Rows of one tile of :func:`grouped_matmul`: the power of two (8 to
+    256) nearest above an even share of ``rows`` over ``groups``, so that
+    a group of average size takes one tile and so reads its weights once."""
+    b = 8
+    while b < 256 and b * groups < rows:
+        b *= 2
+    return b
+
+
+@partial(jax.jit, static_argnums=3)
+def grouped_matmul(rows, groups, weights, block: int):
+    """``out[m] = weights[groups[m]] @ rows[m]`` for every row whose group
+    lies in ``[0, G)``, and zero for the others (an inner join drops
+    them); ``rows`` is ``[M, K]``, ``weights`` ``[G, N, K]``.
+
+    The rows are sorted by group and cut into tiles of ``block`` rows that
+    each lie in one group; a loop over the tiles there are multiplies each
+    by its group's ``[N, K]`` slice.  A group no row names is never read,
+    and no row is multiplied by a group it does not name.  Shapes are
+    static (``M``, ``G`` and ``block`` fix them), so the whole runs inside
+    a compiled program."""
+    m, k = rows.shape
+    g, n, _ = weights.shape
+    gid = jnp.where((groups >= 0) & (groups < g), groups, g)
+    order = jnp.argsort(gid, stable=True)
+    counts = jnp.bincount(gid, length=g + 1)[:g]
+    ends = jnp.cumsum(counts)
+    tiles = (counts + block - 1) // block
+    tile_ends = jnp.cumsum(tiles)
+    # tile t belongs to the first group whose tiles end after it
+    t = jnp.arange(-(-m // block) + g)
+    tg = jnp.minimum(jnp.searchsorted(tile_ends, t, side="right"), g - 1)
+    first = ends[tg] - counts[tg] + (t - tile_ends[tg] + tiles[tg]) * block
+    last = ends[tg]
+    # sorted rows, then one tile of zeros so that no slice is clamped
+    xs = jnp.concatenate([rows[order], jnp.zeros((block, k), rows.dtype)])
+    lanes = jnp.arange(block)
+
+    def tile(i, out):
+        r0 = first[i]
+        x = jax.lax.dynamic_slice_in_dim(xs, r0, block)
+        y = jnp.einsum("bk,nk->bn", x, weights[tg[i]],
+                       precision=CONTRACT_PRECISION)
+        keep = (r0 + lanes < last[i])[:, None]
+        cur = jax.lax.dynamic_slice_in_dim(out, r0, block)
+        return jax.lax.dynamic_update_slice_in_dim(
+            out, jnp.where(keep, y.astype(out.dtype), cur), r0, 0)
+
+    out = jax.lax.fori_loop(0, tile_ends[-1], tile,
+                            jnp.zeros((m + block, n), rows.dtype))
+    return jnp.zeros((m, n), rows.dtype).at[order].set(out[:m])
+
+
+def _try_grouped_join_agg(node: GroupAgg, env, memo, scalars=None,
+                          tracer=None):
+    """Recognise γ_{G, SUM(dot(a, b))}(L ⋈ R) whose join binds R's leading
+    key to a column of L (a value join, the routed expert of each row) and
+    R's other joined keys to keys of L, and run it as a grouped matmul.
+
+    The rows are L's keys that the join does not contract; each takes the
+    slice of R its value names, and a row whose value names no slice of R
+    finds no partner and drops out (an inner join), so no row meets a
+    slice it does not name.  Returns None when the pattern does not
+    apply."""
+    if not isinstance(node.input, Join) or len(node.aggs) != 1:
+        return None
+    out_col, fn, expr = node.aggs[0]
+    if fn != "SUM" or not (isinstance(expr, Call) and expr.fn == "dot"):
+        return None
+    a, b = expr.args
+    if not (isinstance(a, Col) and isinstance(b, Col)):
+        return None
+    join = node.input
+    values = [(rk, e) for rk, e in join.on if isinstance(e, Col)]
+    keyed = [(rk, e) for rk, e in join.on if not isinstance(e, Col)]
+    if len(values) != 1 or not all(isinstance(e, Key) for _, e in keyed):
+        return None
+    left = execute(join.left, env, memo, scalars, tracer)
+    right = execute(join.right, env, memo, scalars, tracer)
+    vkey, vcol = values[0][0], values[0][1].name
+    if right.key_names[0] != vkey or vcol not in left.cols:
+        return None
+    if a.name in left.cols and b.name in right.cols:
+        lcol, rcol = a.name, b.name
+    elif b.name in left.cols and a.name in right.cols:
+        lcol, rcol = b.name, a.name
+    else:
+        return None
+    if not (is_vec(left.col_types[lcol]) and is_vec(right.col_types[rcol])):
+        return None
+    contract = [e.name for _, e in keyed]
+    rows_keys = [k for k in left.key_names if k not in contract]
+    surv = [k for k in right.key_names[1:] if k not in dict(keyed)]
+    if set(node.group_keys) != set(rows_keys) | set(surv):
+        return None
+
+    lsizes = dict(left.keys)
+    laxes = [left.key_names.index(k) for k in rows_keys + contract]
+    larr = left.cols[lcol]
+    larr = jnp.transpose(
+        jnp.broadcast_to(larr, left.key_sizes + larr.shape[-1:]),
+        laxes + [len(laxes)])
+    m = int(np.prod([lsizes[k] for k in rows_keys], dtype=np.int64))
+    larr = larr.reshape(m, -1)
+    # the routed value is constant along the contracted keys
+    gid = jnp.transpose(jnp.broadcast_to(left.cols[vcol], left.key_sizes),
+                        laxes)
+    gid = gid.reshape((m, -1))[:, 0].astype(jnp.int32)
+
+    rsizes = dict(right.keys)
+    rkey_of = {e.name: rk for rk, e in keyed}
+    rax = [0] + [right.key_names.index(k) for k in surv] + [
+        right.key_names.index(rkey_of[k]) for k in contract]
+    rarr = jnp.transpose(right.cols[rcol], rax + [len(rax)])
+    n = int(np.prod([rsizes[k] for k in surv], dtype=np.int64))
+    rarr = rarr.reshape(rsizes[vkey], n, -1)
+    if rarr.shape[-1] != larr.shape[-1]:
+        return None
+
+    res = grouped_matmul(larr, gid, rarr,
+                         grouped_block(m, rsizes[vkey]))
+    got = rows_keys + surv
+    res = res.reshape(tuple(lsizes[k] for k in rows_keys)
+                      + tuple(rsizes[k] for k in surv))
+    out_schema = resolve(node)
+    res = jnp.transpose(res, [got.index(k) for k in out_schema.key_names])
+    return DenseTable(keys=out_schema.keys, cols={out_col: res},
+                      col_types={out_col: out_schema.col_type(out_col)})
+
+
 # ---------------------------------------------------------------------------
 # Main interpreter
 # ---------------------------------------------------------------------------
@@ -775,7 +916,8 @@ def _execute(node: RelNode, env, memo, scalars=None,
         return DenseTable(keys=schema.keys, cols=out_cols, col_types=out_types)
 
     if isinstance(node, GroupAgg):
-        fused = _try_fused_join_agg(node, env, memo, scalars, tracer)
+        fused = (_try_fused_join_agg(node, env, memo, scalars, tracer)
+                 or _try_grouped_join_agg(node, env, memo, scalars, tracer))
         if fused is not None:
             return fused
         t = execute(node.input, env, memo, scalars, tracer)
@@ -796,8 +938,8 @@ def _execute(node: RelNode, env, memo, scalars=None,
     if isinstance(node, Filter):
         t = execute(node.input, env, memo, scalars, tracer)
         op, lhs, rhs = node.predicate
-        l = _eval_key_expr(lhs, t.key_names, t.key_sizes, scalars)
-        r = _eval_key_expr(rhs, t.key_names, t.key_sizes, scalars)
+        l = _eval_key_expr(lhs, t.key_names, t.key_sizes, scalars, t)
+        r = _eval_key_expr(rhs, t.key_names, t.key_sizes, scalars, t)
         mask = {"<=": jnp.less_equal, "<": jnp.less, "==": jnp.equal,
                 ">=": jnp.greater_equal, ">": jnp.greater}[op](l, r)
         mask = jnp.broadcast_to(mask, t.key_sizes)
@@ -824,6 +966,17 @@ def _execute(node: RelNode, env, memo, scalars=None,
                 arr[..., None], t.key_sizes + (varr.shape[-1],))
             col_types[c] = t.col_types[c]
         return DenseTable(keys=schema.keys, cols=cols, col_types=col_types)
+
+    if isinstance(node, TopK):
+        t = execute(node.input, env, memo, scalars, tracer)
+        schema = resolve(node)
+        ax = t.key_names.index(node.order_key)
+        vals = jnp.moveaxis(
+            jnp.broadcast_to(t.cols[node.order_col], t.key_sizes), ax, -1)
+        top, ids = jax.lax.top_k(vals, node.k)
+        return DenseTable(keys=schema.keys,
+                          cols={node.id_col: ids, node.value_col: top},
+                          col_types=dict(schema.cols))
 
     if isinstance(node, Collect):
         t = execute(node.input, env, memo, scalars, tracer)

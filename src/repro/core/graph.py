@@ -26,6 +26,17 @@ paper's Appendix C, plus free-dimension manipulations):
   reshape | squeeze | expand                         free-dim remap (π, fused away)
   concat_rows         append rows to a cache table   (INSERT / cache update)
   identity            pass-through (target of fused shape ops)
+
+Sparse experts (DeepSeekMoE's routed FFN):
+
+  router              x @ W_gateᵀ: one score per (token, expert)  (⋈ + γ)
+  topk                the k best experts of each token, as rows
+                      (t, k) → (e, s)                              (window rank)
+  held_experts        the routed pairs filtered to the experts held
+                      here, re-numbered to their local ids         (π + σ)
+  expert_linear       each routed pair against its expert's slice of a
+                      table keyed (e, j, d)                        (value ⋈ + γ)
+  combine_experts     y(t) = Σ_k s(t, k) · y(t, k)                 (⋈ + γ)
 """
 
 from __future__ import annotations
@@ -163,9 +174,19 @@ def infer_shapes(graph: Graph) -> None:
             graph.annotate(out, ids.dims + (tbl.dims[-1],))
         elif op in ("rmsnorm", "layernorm", "rope", "softmax"):
             graph.annotate(out, ins[0].dims, ins[0].dtype)
-        elif op == "linear":
+        elif op in ("linear", "router"):
             x, w = ins
             graph.annotate(out, x.dims[:-1] + (w.dims[0],))
+        elif op == "topk":
+            graph.annotate(out, ins[0].dims[:-1] + (("k", node.attrs["k"]),))
+        elif op == "held_experts":
+            graph.annotate(out, ins[0].dims)
+        elif op == "expert_linear":
+            _, held, w = ins
+            graph.annotate(out, held.dims + (w.dims[1],))
+        elif op == "combine_experts":
+            y, _ = ins
+            graph.annotate(out, (y.dims[0], y.dims[-1]))
         elif op == "linear_heads":
             x, w = ins
             graph.annotate(out, x.dims[:-1] + (w.dims[0], w.dims[1]))

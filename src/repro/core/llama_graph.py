@@ -10,6 +10,13 @@ of Appendix A:
     {Q,K,V}_weights_L{i} (head_id, row_id, chunk_id, chunk FLOAT[])
     o_weights_L{i} / GLU_W{1,2,3}_L{i} (row_id, chunk_id, chunk FLOAT[])
     {FFN,Attention}_Norm_L{i} / Final_Norm (chunk_id, chunk FLOAT[])
+
+and, for a sparse-expert layer (DeepSeekMoE), in place of the GLU tables:
+
+    Router_L{i}  (expert_id, chunk_id, chunk FLOAT[])        all experts
+    Experts_W{1,2,3}_L{i} (expert_id, row_id, chunk_id, chunk FLOAT[])
+                                                   the experts held here
+    Shared_W{1,2,3}_L{i}  (row_id, chunk_id, chunk FLOAT[])
 """
 
 from __future__ import annotations
@@ -39,10 +46,39 @@ class LlamaSpec:
     d_ff: int
     rope_theta: float = 500000.0
     eps: float = 1e-5
+    # Sparse experts (DeepSeekMoE).  With ``n_experts`` 0 every layer has
+    # the dense SwiGLU of width ``d_ff``.  Otherwise the layers from
+    # ``n_dense_layers`` on route each token over ``n_router`` experts by
+    # softmax gates, keep its ``top_k`` (gates not renormalised) and add
+    # the part of the ``n_experts`` experts held here, ids
+    # ``first_expert`` to ``first_expert + n_experts - 1``, each a SwiGLU
+    # of width ``d_expert``, to a shared SwiGLU of width ``d_shared``.
+    n_experts: int = 0
+    n_router: int = 0
+    first_expert: int = 0
+    top_k: int = 0
+    d_expert: int = 0
+    d_shared: int = 0
+    n_dense_layers: int = 0
+
+    def __post_init__(self):
+        if self.n_experts and not (
+                0 < self.top_k <= self.n_router
+                and 0 <= self.first_expert
+                and self.first_expert + self.n_experts <= self.n_router
+                and self.d_expert > 0 and self.d_shared > 0):
+            raise ValueError(f"inconsistent sparse-expert fields: {self}")
 
     @property
     def head_dim(self) -> int:
         return self.d_model // self.n_heads
+
+    def is_moe_layer(self, layer: int) -> bool:
+        return self.n_experts > 0 and layer >= self.n_dense_layers
+
+    @property
+    def moe_layers(self) -> tuple:
+        return tuple(L for L in range(self.n_layers) if self.is_moe_layer(L))
 
 
 def build_prefill_graph(spec: LlamaSpec, seq_len: int,
@@ -160,11 +196,10 @@ def _build_graph(spec: LlamaSpec, new_tokens: int, cache_len: int,
         x = g.add("add", [x, o], output=f"x_attn_res_L{L}")
 
         xn = g.add("rmsnorm", [x, f"FFN_Norm_L{L}"], eps=spec.eps)
-        h1 = g.add("linear", [xn, f"GLU_W1_L{L}"], out_features=spec.d_ff)
-        h1 = g.add("silu", [h1])
-        h3 = g.add("linear", [xn, f"GLU_W3_L{L}"], out_features=spec.d_ff)
-        hg = g.add("mul", [h1, h3])
-        h2 = g.add("linear", [hg, f"GLU_W2_L{L}"], out_features=d)
+        if spec.is_moe_layer(L):
+            h2 = _moe_ffn(g, spec, xn, L)
+        else:
+            h2 = _swiglu(g, xn, f"GLU_W{{}}_L{L}", spec.d_ff, d)
         x = g.add("add", [x, h2], output=f"x_mlp_res_L{L}")
 
     g.initializers["Final_Norm"] = None
@@ -174,23 +209,83 @@ def _build_graph(spec: LlamaSpec, new_tokens: int, cache_len: int,
     xf = g.add("rmsnorm", [x, "Final_Norm"], eps=spec.eps)
     logits = g.add("linear", [xf, "lm_head"], out_features=spec.vocab,
                    output="logits")
-    g.outputs = ["logits"]
+    # the held routes of each expert layer leave with the logits, for the
+    # engine's routing series
+    g.outputs = ["logits"] + [f"{ROUTES}{L}" for L in spec.moe_layers]
     return g
 
 
+# name prefix of an expert layer's held routes, ``(t, k) → e`` with the
+# local expert id or -1 where the expert is not held here
+ROUTES = "moe_held_L"
+
+
+def _swiglu(g: Graph, xn: str, table: str, width: int, d: int,
+            output: Optional[str] = None) -> str:
+    """``W2 (silu(W1 x) * W3 x)`` over the tables ``table.format(i)``."""
+    h1 = g.add("linear", [xn, table.format(1)], out_features=width)
+    h1 = g.add("silu", [h1])
+    h3 = g.add("linear", [xn, table.format(3)], out_features=width)
+    hg = g.add("mul", [h1, h3])
+    return g.add("linear", [hg, table.format(2)], out_features=d,
+                 output=output)
+
+
+def _moe_ffn(g: Graph, spec: LlamaSpec, xn: str, L: int) -> str:
+    """DeepSeekMoE's FFN over the experts held here:
+    ``Σ_{i ∈ top-k ∩ held} s_i E_i(x) + S(x)``, the gates ``s`` a softmax
+    over all ``n_router`` experts.  Each step is named ``moe_<part>_L<i>``:
+    router, gates, topk, held, the three expert joins, combine, shared."""
+    r = g.add("router", [xn, f"Router_L{L}"], n_experts=spec.n_router,
+              output=f"moe_router_L{L}")
+    p = g.add("softmax", [r], output=f"moe_gates_L{L}")
+    top = g.add("topk", [p], k=spec.top_k, output=f"moe_topk_L{L}")
+    held = g.add("held_experts", [top], first=spec.first_expert,
+                 count=spec.n_experts, output=f"{ROUTES}{L}")
+    a = g.add("expert_linear", [xn, held, f"Experts_W1_L{L}"],
+              output=f"moe_expert_w1_L{L}")
+    a = g.add("silu", [a])
+    b = g.add("expert_linear", [xn, held, f"Experts_W3_L{L}"],
+              output=f"moe_expert_w3_L{L}")
+    ab = g.add("mul", [a, b])
+    y = g.add("expert_linear", [ab, held, f"Experts_W2_L{L}"],
+              output=f"moe_expert_w2_L{L}")
+    routed = g.add("combine_experts", [y, top], output=f"moe_combine_L{L}")
+    shared = _swiglu(g, xn, f"Shared_W{{}}_L{L}", spec.d_shared,
+                     spec.d_model, output=f"moe_shared_L{L}")
+    return g.add("add", [routed, shared])
+
+
 def _layer_weight_dims(spec: LlamaSpec, L: int) -> Dict[str, tuple]:
-    d, dh, ff = spec.d_model, spec.head_dim, spec.d_ff
-    return {
+    """Named dims of layer ``L``'s tables: attention and norms, then the
+    dense SwiGLU or, in an expert layer, the router, the held experts and
+    the shared expert."""
+    d, dh = spec.d_model, spec.head_dim
+    dims = {
         f"Q_weights_L{L}": (("h", spec.n_heads), ("r", dh), ("d", d)),
         f"K_weights_L{L}": (("hk", spec.n_kv), ("r", dh), ("d", d)),
         f"V_weights_L{L}": (("hk", spec.n_kv), ("r", dh), ("d", d)),
         f"o_weights_L{L}": (("j", d), ("d", d)),
-        f"GLU_W1_L{L}": (("j", ff), ("d", d)),
-        f"GLU_W2_L{L}": (("j", d), ("f", ff)),
-        f"GLU_W3_L{L}": (("j", ff), ("d", d)),
         f"Attention_Norm_L{L}": (("d", d),),
         f"FFN_Norm_L{L}": (("d", d),),
     }
+    if not spec.is_moe_layer(L):
+        ff = spec.d_ff
+        dims.update({f"GLU_W1_L{L}": (("j", ff), ("d", d)),
+                     f"GLU_W2_L{L}": (("j", d), ("f", ff)),
+                     f"GLU_W3_L{L}": (("j", ff), ("d", d))})
+        return dims
+    e, fe, fs = spec.n_experts, spec.d_expert, spec.d_shared
+    dims.update({
+        f"Router_L{L}": (("e", spec.n_router), ("d", d)),
+        f"Experts_W1_L{L}": (("e", e), ("j", fe), ("d", d)),
+        f"Experts_W2_L{L}": (("e", e), ("j", d), ("f", fe)),
+        f"Experts_W3_L{L}": (("e", e), ("j", fe), ("d", d)),
+        f"Shared_W1_L{L}": (("j", fs), ("d", d)),
+        f"Shared_W2_L{L}": (("j", d), ("f", fs)),
+        f"Shared_W3_L{L}": (("j", fs), ("d", d)),
+    })
+    return dims
 
 
 # ---------------------------------------------------------------------------
@@ -201,7 +296,7 @@ def _layer_weight_dims(spec: LlamaSpec, L: int) -> Dict[str, tuple]:
 def init_llama_params(spec: LlamaSpec, seed: int = 0) -> Dict[str, np.ndarray]:
     """Random (deterministic) Llama weights in the conventional dense layout."""
     rng = np.random.default_rng(seed)
-    d, dh, ff = spec.d_model, spec.head_dim, spec.d_ff
+    d = spec.d_model
 
     def w(*shape, scale=None):
         scale = scale if scale is not None else 1.0 / math.sqrt(shape[-1])
@@ -213,15 +308,10 @@ def init_llama_params(spec: LlamaSpec, seed: int = 0) -> Dict[str, np.ndarray]:
         "lm_head": w(spec.vocab, d),
     }
     for L in range(spec.n_layers):
-        params[f"Q_weights_L{L}"] = w(spec.n_heads, dh, d)
-        params[f"K_weights_L{L}"] = w(spec.n_kv, dh, d)
-        params[f"V_weights_L{L}"] = w(spec.n_kv, dh, d)
-        params[f"o_weights_L{L}"] = w(d, d)
-        params[f"GLU_W1_L{L}"] = w(ff, d)
-        params[f"GLU_W2_L{L}"] = w(d, ff)
-        params[f"GLU_W3_L{L}"] = w(ff, d)
-        params[f"Attention_Norm_L{L}"] = np.ones(d, np.float32)
-        params[f"FFN_Norm_L{L}"] = np.ones(d, np.float32)
+        for name, dims in _layer_weight_dims(spec, L).items():
+            shape = tuple(n for _, n in dims)
+            params[name] = (np.ones(shape, np.float32)
+                            if name.endswith(f"_Norm_L{L}") else w(*shape))
     return params
 
 

@@ -30,8 +30,8 @@ from repro.core import relational as ra
 from repro.core.graph import Graph, Node
 from repro.core.relational import (
     Collect, Filter, GroupAgg, Join, KeyParam, Param, Project, RelNode,
-    RelSchema, Scan, Unnest, add, call, col, const, div, floordiv, key, mod,
-    mul, sub, SCALAR, VEC,
+    RelSchema, Scan, TopK, Unnest, add, call, col, const, div, floordiv, key,
+    mod, mul, sub, SCALAR, VEC,
 )
 
 NEG_INF = -1e30
@@ -518,6 +518,90 @@ class RelCompiler:
         return Rel(plan=sc, kind="chunked", keys=tuple(cache_keys),
                    col=new.col, chunk=new.chunk, width=new.width)
 
+    # -- sparse experts (DeepSeekMoE) -----------------------------------------
+
+    def map_router(self, node: Node) -> Rel:
+        """Router scores: ``γ_{(t,e), SUM(dot)}(R_X ⋈_c R_Wg)`` — a matmul
+        whose result stays one scalar row per (token, expert), the relation
+        the softmax and the top-k read."""
+        x = self.bind[node.inputs[0]]
+        n = node.attrs["n_experts"]
+        w = self._weight_scan(node.inputs[1], (("e", n), ("c", x.n_chunks)),
+                              vec_width=x.chunk)
+        j = Join(left=x.plan, right=w, on=[("c", key("c"))])
+        agg = GroupAgg(input=j, group_keys=[k for k, _ in x.keys] + ["e"],
+                       aggs=[("s", "SUM", call("dot", col(x.col),
+                                               col("chunk")))])
+        return Rel(plan=agg, kind="scalar", keys=x.keys + (("e", n),),
+                   col="s")
+
+    def map_topk(self, node: Node) -> Rel:
+        """Per token row ``ORDER BY s DESC LIMIT k``: the routed pairs
+        ``(t, k) → (e, s)``, the expert id and its gate."""
+        p = self.bind[node.inputs[0]]
+        k = node.attrs["k"]
+        top = TopK(input=p.plan, order_key=p.keys[-1][0], order_col=p.col,
+                   k=k, rank_key="k", id_col="e", value_col="s")
+        return Rel(plan=top, kind="scalar", keys=p.keys[:-1] + (("k", k),),
+                   col="s")
+
+    def map_held_experts(self, node: Node) -> Rel:
+        """The routed pairs whose expert is held here, ``first <= e <
+        first + count``, with the expert re-numbered to its row of the
+        local expert tables; the others are filtered out (their id reads
+        -1, which no expert table holds)."""
+        top = self.bind[node.inputs[0]]
+        first, count = node.attrs["first"], node.attrs["count"]
+        local = Project(input=top.plan, keys=None,
+                        exprs=[("e", None, sub(col("e"), const(first)))])
+        held = Filter(input=local,
+                      predicate=("==", floordiv(col("e"), const(count)),
+                                 const(0)),
+                      masked_value=-1.0)
+        return Rel(plan=held, kind="scalar", keys=top.keys, col="e")
+
+    def map_expert_linear(self, node: Node) -> Rel:
+        """One matmul of the routed experts: each held pair ``(t, k)``
+        joins its token's activation and, on ``W.e = held.e``, its expert's
+        slice of ``W(e, j, c, chunk)``; ``γ_{(t,k,j), SUM(dot)}``.  A pair
+        outside the held experts joins no slice and drops out."""
+        x = self.bind[node.inputs[0]]
+        held = self.bind[node.inputs[1]]
+        dims = self.g.info(node.inputs[2]).dims
+        n_exp, out_f = dims[0][1], dims[1][1]
+        w = self._weight_scan(node.inputs[2],
+                              (("e", n_exp), ("j", out_f),
+                               ("c", x.n_chunks)), vec_width=x.chunk)
+        if x.keys == held.keys:
+            # a pair's own activation (the second matmul): bring its expert
+            left = Join(left=x.plan, right=held.plan,
+                        on=_identity_on(held.keys))
+        else:
+            # a token's activation, once for each of its pairs
+            left = Join(left=x.plan, right=held.plan,
+                        on=_identity_on(x.keys))
+        j = Join(left=left, right=w, on=[("e", col("e")), ("c", key("c"))])
+        agg = GroupAgg(input=j,
+                       group_keys=[k for k, _ in held.keys] + ["j"],
+                       aggs=[("s", "SUM", call("dot", col(x.col),
+                                               col("chunk")))])
+        plan = self._rechunk_scalar(agg, held.keys, "j", out_f, "s")
+        return Rel(plan=plan, kind="chunked", keys=held.keys,
+                   chunk=self._eff(out_f), width=out_f)
+
+    def map_combine_experts(self, node: Node) -> Rel:
+        """``y(t) = γ_{(t,c), SUM(s · y)}(Y ⋈_{t,k} R_pairs)``: each
+        token's routed outputs weighted by their gates (not
+        renormalised)."""
+        y = self.bind[node.inputs[0]]
+        top = self.bind[node.inputs[1]]
+        j = Join(left=y.plan, right=top.plan, on=_identity_on(top.keys))
+        t_dim = top.keys[0]
+        agg = GroupAgg(input=j, group_keys=[t_dim[0], "c"],
+                       aggs=[(y.col, "SUM", mul(col(y.col), col("s")))])
+        return Rel(plan=agg, kind="chunked", keys=(t_dim,), col=y.col,
+                   chunk=y.chunk, width=y.width)
+
     # -- driver ---------------------------------------------------------------
 
     OP_RULES = {
@@ -535,6 +619,11 @@ class RelCompiler:
         "merge_heads": map_merge_heads,
         "scale": map_scale,
         "concat_rows": map_concat_rows,
+        "router": map_router,
+        "topk": map_topk,
+        "held_experts": map_held_experts,
+        "expert_linear": map_expert_linear,
+        "combine_experts": map_combine_experts,
     }
 
     def compile(self) -> RelPipeline:

@@ -33,6 +33,14 @@ Node vocabulary
                                           with a new position key (DuckDB UNNEST)
   Collect(input, key, vec_col)         — inverse: fold a dense key into a vector
                                           (collect_as_array in Appendix B)
+  TopK(input, order_key, order_col, k) — per-row ORDER BY col DESC LIMIT k
+                                          over one key (a ROW_NUMBER window);
+                                          the key's value becomes a column
+
+A Join whose condition binds a key of the right table to a *column* of the
+left (a value join, ``vocabulary.tok = ids.s``) is an inner join: a left
+row whose value names no row of the right table drops out.  A Filter's
+predicate may read columns as well as keys.
 """
 
 from __future__ import annotations
@@ -252,7 +260,8 @@ class GroupAgg(RelNode):
 @dataclasses.dataclass
 class Filter(RelNode):
     input: RelNode
-    # predicate over keys: (op, lhs Expr, rhs Expr) with op ∈ {<=, <, ==, >=}
+    # predicate over keys and columns: (op, lhs Expr, rhs Expr) with
+    # op ∈ {<=, <, ==, >=}
     predicate: Tuple[str, Expr, Expr]
     # identity element used by the consuming aggregate for masked-out rows
     masked_value: float = 0.0
@@ -274,7 +283,26 @@ class Collect(RelNode):
     vec_col: str = "chunk"
 
 
-REL_NODE_TYPES = (Scan, Project, Join, GroupAgg, Filter, Unnest, Collect)
+@dataclasses.dataclass
+class TopK(RelNode):
+    """The ``k`` rows of largest ``order_col`` along ``order_key``, for
+    every combination of the input's other keys, in descending order:
+    ``ROW_NUMBER() OVER (PARTITION BY <other keys> ORDER BY order_col
+    DESC) - 1 < k``.  The rank becomes the dense key ``rank_key`` (0 the
+    largest), the consumed key's value the column ``id_col`` and the
+    ordered value the column ``value_col``.  Ties go to the lower key."""
+
+    input: RelNode
+    order_key: str
+    order_col: str
+    k: int
+    rank_key: str = "k"
+    id_col: str = "e"
+    value_col: str = "s"
+
+
+REL_NODE_TYPES = (Scan, Project, Join, GroupAgg, Filter, Unnest, Collect,
+                  TopK)
 
 
 def walk(node: RelNode):
@@ -363,6 +391,12 @@ def resolve(node: RelNode) -> RelSchema:
         cols = tuple((c, t) for c, t in in_s.cols if c != node.vec_col) + (
             (node.elem_col, SCALAR),)
         node.schema = RelSchema(keys=keys, cols=cols)
+    elif isinstance(node, TopK):
+        in_s = resolve(node.input)
+        keys = tuple((k, s) for k, s in in_s.keys if k != node.order_key)
+        node.schema = RelSchema(
+            keys=keys + ((node.rank_key, node.k),),
+            cols=((node.id_col, SCALAR), (node.value_col, SCALAR)))
     elif isinstance(node, Collect):
         in_s = resolve(node.input)
         w = in_s.key_size(node.fold_key)

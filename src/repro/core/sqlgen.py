@@ -20,8 +20,8 @@ from typing import Dict, List, Optional, Tuple
 
 from repro.core.relational import (
     BinOp, Call, Col, Collect, Const, Expr, Filter, GroupAgg, Join, Key,
-    KeyParam, Param, Project, RelNode, RelSchema, Scan, Unnest, expr_type,
-    is_vec, resolve, vec_width, SCALAR,
+    KeyParam, Param, Project, RelNode, RelSchema, Scan, TopK, Unnest,
+    expr_type, is_vec, resolve, vec_width, SCALAR,
 )
 from repro.core.executor import plan_provenance
 from repro.core.opmap import RelPipeline
@@ -267,6 +267,21 @@ class SQLGenerator:
                     f"{node.elem_key}, U.{node.elem_col} FROM {src} AS S, "
                     f"UNNEST(S.{_sn(node.vec_col)}) WITH ORDINALITY AS "
                     f"U({node.elem_col}, ord)")
+
+        if isinstance(node, TopK):
+            # per-partition ranking: ROW_NUMBER over the ordered column,
+            # ties to the lower key, kept below k
+            src = self._ref(node.input, ctes)
+            in_s = resolve(node.input)
+            part = [_sn(k) for k in in_s.key_names if k != node.order_key]
+            over = f"PARTITION BY {', '.join(part)} " if part else ""
+            rank, key_ = _sn(node.rank_key), _sn(node.order_key)
+            ranked = (
+                f"SELECT {''.join(p + ', ' for p in part)}"
+                f"ROW_NUMBER() OVER ({over}ORDER BY {_sn(node.order_col)} "
+                f"DESC, {key_}) - 1 AS {rank}, {key_} AS {_sn(node.id_col)}, "
+                f"{_sn(node.order_col)} AS {_sn(node.value_col)} FROM {src}")
+            return f"SELECT * FROM ({ranked}) AS W WHERE {rank} < {node.k}"
 
         if isinstance(node, Collect):
             src = self._ref(node.input, ctes)

@@ -1,14 +1,17 @@
-"""Build the served relational path from a model name.
+"""Build the served relational path from a model name or configuration.
 
 The one construction path behind ``examples/serve_http.py`` and
-``chip_smoke.py``: a registry name (or ``"tiny"``) and a layer count give a
-``LlamaSpec``; a spec and its weights give an ``AsyncLLMServer`` over a
-``RelationalEngine``.  Also places JAX's persistent compile cache for both
-entry points.
+``chip_smoke.py``: a registry name (or ``"tiny"``), or a model's published
+``config.json`` read by its ``model_type`` (:func:`spec_from_config`), and
+a layer count give a ``LlamaSpec``; a spec and its weights give an
+``AsyncLLMServer`` over a ``RelationalEngine``.  Also places JAX's
+persistent compile cache for both entry points.
 """
 
 from __future__ import annotations
 
+import dataclasses
+import json
 import os
 from pathlib import Path
 from typing import Dict, Optional
@@ -44,13 +47,81 @@ def enable_compile_cache() -> str:
     return path
 
 
+# model_type of a published config.json -> the block built for it
+DENSE_TYPES = ("llama", "mistral")
+MOE_TYPES = ("deepseek",)
+
+
+def spec_from_config(cfg: dict) -> LlamaSpec:
+    """The relational path's spec of a model from the keys of its published
+    ``config.json``, read by ``model_type``:
+
+    * ``llama``, ``mistral``: the dense GQA decoder;
+    * ``deepseek`` (DeepSeekMoE): multi-head attention, then
+      ``first_k_dense_replace`` layers with the dense SwiGLU of width
+      ``intermediate_size`` and after them expert layers: softmax gates
+      over the router's experts, the ``num_experts_per_tok`` best kept and
+      not renormalised, experts of width ``moe_intermediate_size``, and
+      ``n_shared_experts`` shared experts merged into one SwiGLU.
+      ``n_routed_experts`` counts the experts held here; a chip that holds
+      a share of them gives the router's width as ``router_experts`` and
+      its first held id as ``first_held_expert`` (by default all are held).
+
+    Any other ``model_type``, or a key that asks for a block other than
+    these, raises ``ValueError`` before a weight is drawn."""
+    kind = cfg.get("model_type")
+    if kind not in DENSE_TYPES + MOE_TYPES:
+        raise ValueError(f"model_type {kind!r}: the relational path builds "
+                         f"{', '.join(DENSE_TYPES + MOE_TYPES)}")
+    d, h = int(cfg["hidden_size"]), int(cfg["num_attention_heads"])
+    head_dim = cfg.get("head_dim")
+    if d % h or (head_dim is not None and int(head_dim) != d // h):
+        raise ValueError(f"head_dim {head_dim}: the relational path derives "
+                         f"it as hidden_size / num_attention_heads "
+                         f"({d} / {h})")
+    unbuilt = {k: cfg[k] for k, ok in (
+        ("rope_scaling", None), ("tie_word_embeddings", False),
+        ("hidden_act", "silu"), ("scoring_func", "softmax"),
+        ("norm_topk_prob", False), ("topk_method", "greedy"),
+        ("moe_layer_freq", 1)) if cfg.get(k, ok) != ok}
+    if unbuilt:
+        raise ValueError(f"{kind}: the relational path does not build "
+                         f"{unbuilt}")
+    spec = LlamaSpec(vocab=int(cfg["vocab_size"]), d_model=d,
+                     n_layers=int(cfg["num_hidden_layers"]), n_heads=h,
+                     n_kv=int(cfg.get("num_key_value_heads", h)),
+                     d_ff=int(cfg["intermediate_size"]),
+                     rope_theta=float(cfg["rope_theta"]),
+                     eps=float(cfg["rms_norm_eps"]))
+    if kind in DENSE_TYPES:
+        return spec
+    held, width = int(cfg["n_routed_experts"]), int(
+        cfg["moe_intermediate_size"])
+    return dataclasses.replace(
+        spec, n_experts=held, n_router=int(cfg.get("router_experts", held)),
+        first_expert=int(cfg.get("first_held_expert", 0)),
+        top_k=int(cfg["num_experts_per_tok"]), d_expert=width,
+        d_shared=int(cfg["n_shared_experts"]) * width,
+        n_dense_layers=int(cfg["first_k_dense_replace"]))
+
+
 def model_spec(model: str, n_layers: Optional[int] = None) -> LlamaSpec:
-    """``"tiny"`` or a registered dense-Llama config, depth cut to
-    ``n_layers`` (widths always as registered)."""
+    """``"tiny"``, a registered dense-Llama config, or the path of a
+    published ``config.json`` (:func:`spec_from_config`), depth cut to
+    ``n_layers`` (widths always as given)."""
     if model == "tiny":
         if n_layers is not None:
             raise ValueError("the tiny model has a fixed depth")
         return TINY
+    if model.endswith(".json"):
+        with open(model) as f:
+            spec = spec_from_config(json.load(f))
+        if n_layers is None:
+            return spec
+        if not 1 <= n_layers <= spec.n_layers:
+            raise ValueError(f"{model}: n_layers {n_layers} outside [1, "
+                             f"{spec.n_layers}]")
+        return dataclasses.replace(spec, n_layers=n_layers)
     return config_to_spec(get_config(model), n_layers)
 
 

@@ -45,18 +45,36 @@ PHASE_SERIES = {
 }
 
 
+# series of the sparse-expert layers, labelled phase="prefill" or
+# phase="decode", read from the held routes that leave a plan with its
+# logits (one observation per expert layer and plan for the histograms)
+MOE_SERIES = {
+    "moe_routed_pairs_total": "(token, expert) pairs computed here",
+    "moe_experts_touched": "distinct held experts of an expert layer",
+    "moe_expert_load_max": "most tokens on one held expert of a layer",
+}
+MOE_BUCKETS = (0, 1, 2, 4, 8, 16, 32, 64, 128, 256, 512, 1024, 2048, 4096)
+
+
+def route_outputs(pipe) -> List[str]:
+    """The outputs of ``pipe`` that hold an expert layer's held routes."""
+    return [o for o in pipe.outputs if o.startswith(lg.ROUTES)]
+
+
 def batched_decode_tick(pipe, weights, views, tables, inputs, seq_ids,
                         positions):
     """One batched decode tick as a pure function, for ``jax.jit``: the
     bucket's plan over the weight tables, the batch's cache views and the
     tick's inputs (``token_ids``, ``freq_each_token``), then the row
     writeback of :func:`~repro.serving.kvcache.appended_rows` into the
-    pool ``tables``.  Returns the logits column, the updated views and the
+    pool ``tables``.  Returns the logits column, the held routes of the
+    expert layers (none for a dense model), the updated views and the
     updated pool tables."""
     env = {**weights, **views, **inputs}
     outs, env = run_pipeline(pipe, env, scalars={"seq_positions": positions})
     views = {name: env[name] for name in views}
-    return (outs["logits"].cols["v"], views,
+    routes = tuple(outs[o].cols["e"] for o in route_outputs(pipe))
+    return (outs["logits"].cols["v"], routes, views,
             appended_rows(tables, views, seq_ids, positions))
 
 
@@ -565,22 +583,57 @@ class RelationalEngine:
         return self.metrics.histogram(series, PHASE_SERIES[series],
                                       phase=phase)
 
+    def _fetch(self, logits, routes, phase: str, rows: int) -> np.ndarray:
+        """Host copy of a plan's ``logits``.  With a registry, its held
+        ``routes`` come in the same transfer and the ``MOE_SERIES`` count
+        them over the first ``rows`` tokens."""
+        if self.metrics is None or not routes:
+            return np.asarray(logits)
+        logits, routes = jax.device_get((logits, list(routes)))
+        self.observe_routes(routes, phase, rows)
+        return logits
+
+    def observe_routes(self, routes, phase: str, rows: int) -> None:
+        """Record one plan's routes, ``[tokens, top_k]`` local expert ids
+        (-1 not held) per expert layer, of which the first ``rows`` tokens
+        are real (a batch bucket's padding repeats a sequence)."""
+        m = self.metrics
+        pairs = m.counter("moe_routed_pairs_total",
+                          MOE_SERIES["moe_routed_pairs_total"], phase=phase)
+        touched = m.histogram("moe_experts_touched",
+                              MOE_SERIES["moe_experts_touched"],
+                              buckets=MOE_BUCKETS, phase=phase)
+        load = m.histogram("moe_expert_load_max",
+                           MOE_SERIES["moe_expert_load_max"],
+                           buckets=MOE_BUCKETS, phase=phase)
+        for ids in routes:
+            ids = np.asarray(ids).reshape(np.shape(ids)[0], -1)[:rows]
+            held = ids[ids >= 0].astype(np.int64)
+            per = np.bincount(held, minlength=1)
+            pairs.inc(int(held.size))
+            touched.observe(int(np.count_nonzero(per)))
+            load.observe(int(per.max()))
+
     def _run_prefill(self, T: int, suffix: bool, env, position: int):
         """The prefill plan over ``env`` and its final-position logits on
         the host; returns ``(env, logits)``."""
         if self.pager is not None:
             self.pager.prefetch(["vocabulary"])
+        pipe = self._prefill_pipe(T, suffix=suffix)
         with traced("prefill.dispatch",
                     hist=self._phase_hist("engine_dispatch_seconds",
                                           "prefill")):
-            outs, env = run_pipeline(self._prefill_pipe(T, suffix=suffix),
-                                     env,
+            outs, env = run_pipeline(pipe, env,
                                      scalars={"cache_position": position},
                                      tracer=self.tracer,
                                      shard_runner=self._shard_runner)
         with traced("prefill.sync",
                     hist=self._phase_hist("engine_sync_seconds", "prefill")):
-            logits = self._final_logits(outs["logits"])
+            logits = self._fetch(
+                outs["logits"].cols["v"],
+                [outs[o].cols["e"] for o in route_outputs(pipe)],
+                "prefill", T)
+        logits = logits.reshape(logits.shape[0], -1)[-1, : self.spec.vocab]
         return env, logits
 
     @property
@@ -949,7 +1002,7 @@ class BatchedDecoder:
             if path == "compiled":
                 # the row writeback runs inside the compiled tick
                 tick, names = eng._decode_tick(bucket, pipe)
-                logits, views, tables = tick(
+                logits, routes, views, tables = tick(
                     {n: eng.env_base[n] for n in names}, views,
                     self.pool.tables, inputs, np.asarray(ids, np.int32),
                     positions)
@@ -963,6 +1016,7 @@ class BatchedDecoder:
                                                           jnp.int32)},
                     tracer=eng.tracer, shard_runner=eng._shard_runner)
                 logits = outs["logits"].cols["v"]
+                routes = [outs[o].cols["e"] for o in route_outputs(pipe)]
                 views = {name: env[name] for name in self.pool.tables}
         self.decode_calls += 1
         # the tick's only cache mutation is one appended row per sequence
@@ -983,7 +1037,7 @@ class BatchedDecoder:
             with traced("decode.sync",
                         hist=eng._phase_hist("engine_sync_seconds",
                                              "decode")):
-                logits = np.asarray(logits)
+                logits = eng._fetch(logits, routes, "decode", B)
             logits = logits.reshape(bucket, -1)[:B, : eng.spec.vocab]
             next_toks = [int(t) for t in np.argmax(logits, axis=1)]
         self.last_logits = logits

@@ -1,4 +1,5 @@
-"""Compile the Pallas kernels for a described TPU v5e at Llama-3-8B widths.
+"""Compile the Pallas kernels for a described TPU v5e at Llama-3-8B widths,
+and the executor's grouped matmul of routed experts at DeepSeekMoE-16B's.
 
 Nothing runs: the TPU compiler that ships with libtpu lowers each kernel
 for a chip that is described, not attached, and the test checks that
@@ -21,6 +22,7 @@ import pytest
 from jax.sharding import SingleDeviceSharding
 
 from repro.configs.llama3_8b import CONFIG as LLAMA3_8B
+from repro.core.executor import grouped_block, grouped_matmul
 from repro.kernels import ops
 
 
@@ -74,3 +76,21 @@ def test_paged_attention_compiles_at_page_geometry(one_chip):
                           pool, pool, ((B, max_pages), jnp.int32),
                           ((B,), jnp.int32), sharding=one_chip)
     assert "tpu_custom_call" in text
+
+
+@pytest.mark.parametrize("rows", [16 * 6, 512 * 6])
+def test_grouped_matmul_compiles_at_expert_width(one_chip, rows):
+    """The routed pairs of a decode tick of 16 and of a 512-token prefill
+    (top-6) against 16 held experts of 2048 -> 1408: a loop over tiles of
+    one expert each, never the dense form that multiplies every row by
+    every expert, and no copy of an expert's weights."""
+    g, n, k = 16, 1408, 2048
+    fn = functools.partial(grouped_matmul, block=grouped_block(rows, g))
+    args = [jax.ShapeDtypeStruct(s, dt, sharding=one_chip)
+            for s, dt in (((rows, k), jnp.float32), ((rows,), jnp.int32),
+                          ((g, n, k), jnp.float32))]
+    compiled = jax.jit(fn).lower(*args).compile()
+    text = compiled.as_text()
+    assert " while(" in text
+    assert f"f32[{g},{rows},{n}]" not in text
+    assert compiled.memory_analysis().temp_size_in_bytes < n * k * 4
