@@ -6,7 +6,10 @@ The batched decode tick runs it under ``jax.jit``
 (``serving/engine.batched_decode_tick``, one program per batch bucket): the
 weight tables enter as arguments, never as constants, and the batch's cache
 views and the slot pool's tables are donated, so the tick updates the cache
-without a second copy.  Prefill and single-session decode run it eagerly.
+without a second copy.  A cold prefill runs it under ``jax.jit`` too
+(``serving/engine.cold_prefill``, one program per prompt-length bucket).
+The suffix prefill of a prefix hit and single-session decode run it
+eagerly.
 """
 
 from __future__ import annotations

@@ -37,10 +37,11 @@ from repro.serving.kvcache import appended_rows
 from repro.serving.pager import WeightPager
 
 # host-clock histograms of one served phase, labelled phase="prefill" or
-# phase="decode": the run_pipeline call, which dispatches the plan, and
-# the copy of its logits to the host, where the host waits for the device
+# phase="decode": the dispatch of the plan (one compiled program's launch,
+# or run_pipeline op by op), and the copy of its logits to the host, where
+# the host waits for the device
 PHASE_SERIES = {
-    "engine_dispatch_seconds": "host time in one run_pipeline call",
+    "engine_dispatch_seconds": "host time dispatching one plan",
     "engine_sync_seconds": "host wait for a plan's logits",
 }
 
@@ -76,6 +77,22 @@ def batched_decode_tick(pipe, weights, views, tables, inputs, seq_ids,
     routes = tuple(outs[o].cols["e"] for o in route_outputs(pipe))
     return (outs["logits"].cols["v"], routes, views,
             appended_rows(tables, views, seq_ids, positions))
+
+
+def cold_prefill(pipe, weights, caches, inputs, last):
+    """One cold prefill as a pure function, for ``jax.jit``: the bucket's
+    prefill plan over the weight tables, empty ``caches`` and the prompt's
+    ``inputs`` (``token_ids``, ``freq_each_token``) padded at the end to
+    the bucket.  Returns the logits row of token ``last``, the last real
+    one, the held routes of the expert layers over every row of the
+    bucket, and the filled cache tables."""
+    env = {**weights, **caches, **inputs}
+    outs, env = run_pipeline(pipe, env, scalars={"cache_position": 0})
+    v = outs["logits"].cols["v"]
+    row = jax.lax.dynamic_index_in_dim(v.reshape(v.shape[0], -1), last,
+                                       keepdims=False)
+    routes = tuple(outs[o].cols["e"] for o in route_outputs(pipe))
+    return row, routes, {name: env[name] for name in caches}
 
 
 @dataclasses.dataclass
@@ -272,8 +289,10 @@ class RelationalEngine:
         # new plan
         self._batched_pipes: Dict[tuple, object] = {}
         # the compiled batched decode tick of each of those plans, under
-        # the same keys (see decode_path)
+        # the same keys, and the compiled cold prefill of each prompt-length
+        # bucket, keyed by (bucket, shards) (see runs_compiled)
         self._decode_ticks: Dict[tuple, object] = {}
+        self._prefill_ticks: Dict[tuple, object] = {}
         # paged residency: duplicate column copies compete with the working
         # set, so the global residency pass runs under the pager budget;
         # in-memory residency is unbounded.  One ResidencyPool is shared by
@@ -517,15 +536,22 @@ class RelationalEngine:
         return self._batched_pipes[key]
 
     @property
+    def runs_compiled(self) -> bool:
+        """Whether the batched decode tick and the cold prefill run as
+        compiled XLA programs (:meth:`_decode_tick`, :meth:`_prefill_tick`):
+        when the weights are held in memory, no recorder is attached (it
+        blocks on every step, which a traced program cannot) and the plans
+        are unsharded.  Otherwise their plans are dispatched op by op, as
+        the suffix prefill of a prefix hit and single-session decode
+        always are."""
+        return self.pager is None and self.tracer is None and self.shards == 1
+
+    @property
     def decode_path(self) -> str:
         """How a batched decode tick runs: ``"compiled"``, one XLA program
-        per batch bucket (:meth:`_decode_tick`), when the weights are held
-        in memory, no recorder is attached (it blocks on every step, which
-        a traced program cannot) and the plans are unsharded; otherwise
-        ``"eager"``, the plan dispatched op by op."""
-        if self.pager is None and self.tracer is None and self.shards == 1:
-            return "compiled"
-        return "eager"
+        per batch bucket, where :attr:`runs_compiled`; otherwise
+        ``"eager"``."""
+        return "compiled" if self.runs_compiled else "eager"
 
     def _decode_tick(self, batch: int, pipe):
         """``(tick, weight names)``: the compiled tick over ``pipe``, the
@@ -545,6 +571,28 @@ class RelationalEngine:
                 tuple(self.env_base))
         return self._decode_ticks[key]
 
+    def _prefill_tick(self, bucket: int, pipe):
+        """``(prefill, weight names)``: the compiled cold prefill over
+        ``pipe``, the bucket's registered prefill plan, and the weight
+        tables to pass it, as :meth:`_decode_tick` gives them.  The program
+        makes its own empty cache tables and donates nothing: the prefix
+        cache holds the tables it returns by reference.  The last real
+        token's row is a traced index, so one program serves every prompt
+        length of the bucket."""
+        key = (bucket, self.shards)
+        if key not in self._prefill_ticks:
+            def prefill(weights, inputs, last):  # names it jit_prefill
+                return cold_prefill(pipe, weights, self._empty_caches(),
+                                    inputs, last)
+            self._prefill_ticks[key] = (jax.jit(prefill),
+                                        tuple(self.env_base))
+        return self._prefill_ticks[key]
+
+    def _prefill_bucket(self, T: int) -> int:
+        """Length bucket a compiled prefill of ``T`` tokens runs at: ``T``
+        rounded up to a multiple of the chunk size, at most ``max_len``."""
+        return min(-(-T // self.cs) * self.cs, self.max_len)
+
     @staticmethod
     def _decode_bucket(batch: int) -> int:
         """Batch-size bucket (next power of two) a tick's plan is keyed by."""
@@ -560,11 +608,14 @@ class RelationalEngine:
         # wrap cold arrays at the planner's per-table chunk sizes
         return self.env_base.copy()
 
+    def _empty_caches(self):
+        return lg.empty_cache_tables(self.spec, cache_len=self.max_len,
+                                     chunk_size=self.cs,
+                                     layout=self.cache_layout)
+
     def _fresh_env(self):
         env = self._weights_env()
-        env.update(lg.empty_cache_tables(self.spec, cache_len=self.max_len,
-                                         chunk_size=self.cs,
-                                         layout=self.cache_layout))
+        env.update(self._empty_caches())
         return env
 
     def _argmax_token(self, out_table) -> int:
@@ -614,9 +665,49 @@ class RelationalEngine:
             touched.observe(int(np.count_nonzero(per)))
             load.observe(int(per.max()))
 
+    def _count_prefill(self, path: str, padded: int) -> None:
+        if self.metrics is not None:
+            self.metrics.counter("engine_prefill_total",
+                                 "prefills, by how they ran", path=path).inc()
+            self.metrics.counter(
+                "engine_prefill_padded_tokens_total",
+                "rows a compiled prefill computed past the prompt").inc(
+                    padded)
+
+    def _run_compiled_prefill(self, prompt: List[int]):
+        """The cold prefill of ``prompt`` as one compiled program of its
+        length bucket (:meth:`_prefill_tick`), the prompt padded at the end
+        with token 0 at the positions that follow it; returns the filled
+        cache tables and the last real token's logits on the host.  Under
+        the causal mask no real row attends to a padded one; the padded
+        rows fill cache positions past the prompt, which decode masks
+        until it overwrites them."""
+        T = len(prompt)
+        bucket = self._prefill_bucket(T)
+        ids = np.zeros(bucket, np.int32)
+        ids[:T] = prompt
+        inputs = {"token_ids": lg.token_table(ids),
+                  "freq_each_token": lg.rope_freq_table(
+                      np.arange(bucket), self.spec.head_dim,
+                      self.spec.rope_theta)}
+        self._count_prefill("compiled", bucket - T)
+        prefill, names = self._prefill_tick(bucket,
+                                            self._prefill_pipe(bucket))
+        with traced("prefill.dispatch",
+                    hist=self._phase_hist("engine_dispatch_seconds",
+                                          "prefill")):
+            logits, routes, caches = prefill(
+                {n: self.env_base[n] for n in names}, inputs,
+                np.int32(T - 1))
+        with traced("prefill.sync",
+                    hist=self._phase_hist("engine_sync_seconds", "prefill")):
+            logits = self._fetch(logits, routes, "prefill", T)
+        return caches, logits.reshape(-1)[: self.spec.vocab]
+
     def _run_prefill(self, T: int, suffix: bool, env, position: int):
-        """The prefill plan over ``env`` and its final-position logits on
-        the host; returns ``(env, logits)``."""
+        """The prefill plan over ``env``, dispatched op by op, and its
+        final-position logits on the host; returns ``(env, logits)``."""
+        self._count_prefill("eager", 0)
         if self.pager is not None:
             self.pager.prefetch(["vocabulary"])
         pipe = self._prefill_pipe(T, suffix=suffix)
@@ -689,6 +780,7 @@ class RelationalEngine:
         self._prefill_pipes.clear()
         self._batched_pipes.clear()
         self._decode_ticks.clear()
+        self._prefill_ticks.clear()
         self.replans += 1
         if self.metrics is not None:
             self.metrics.counter(
@@ -700,16 +792,25 @@ class RelationalEngine:
     # -- incremental session API (used by the continuous-batching scheduler) --
 
     def start_session(self, prompt: List[int]):
-        """Prefill; returns a session dict holding env + cursor + first tok."""
+        """Prefill; returns a session dict holding env + cursor + first tok.
+        The prefill runs as one compiled program of the prompt's length
+        bucket where :attr:`runs_compiled`, else op by op."""
         T = len(prompt)
-        env_span = (self.tracer.span("session_env", cat="decoder")
-                    if self.tracer is not None else contextlib.nullcontext())
-        with env_span:
-            env = self._fresh_env()
-            env["token_ids"] = lg.token_table(np.asarray(prompt, np.int32))
-            env["freq_each_token"] = lg.rope_freq_table(
-                np.arange(T), self.spec.head_dim, self.spec.rope_theta)
-        env, logits = self._run_prefill(T, False, env, 0)
+        if self.runs_compiled:
+            caches, logits = self._run_compiled_prefill(prompt)
+            env = self._weights_env()
+            env.update(caches)
+        else:
+            env_span = (self.tracer.span("session_env", cat="decoder")
+                        if self.tracer is not None
+                        else contextlib.nullcontext())
+            with env_span:
+                env = self._fresh_env()
+                env["token_ids"] = lg.token_table(
+                    np.asarray(prompt, np.int32))
+                env["freq_each_token"] = lg.rope_freq_table(
+                    np.arange(T), self.spec.head_dim, self.spec.rope_theta)
+            env, logits = self._run_prefill(T, False, env, 0)
         return {"env": env, "pos": T, "tok": int(np.argmax(logits)),
                 "logits": logits}
 

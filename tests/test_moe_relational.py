@@ -62,8 +62,9 @@ def engine(cfg, params, **kw):
 
 
 def eager(monkeypatch):
-    monkeypatch.setattr(RelationalEngine, "decode_path",
-                        property(lambda self: "eager"))
+    """Dispatch the decode tick and the cold prefill op by op."""
+    monkeypatch.setattr(RelationalEngine, "runs_compiled",
+                        property(lambda self: False))
 
 
 # -- the program against the reference ------------------------------------
@@ -101,10 +102,13 @@ def test_prefill_then_batched_decode_match_the_reference(path, row2col,
     assert err < TOL
 
 
-def test_the_shares_add_up_to_the_uncut_layer():
+def test_the_shares_add_up_to_the_uncut_layer(monkeypatch):
     """One expert layer cut four ways (16 experts, 4 held by each share):
     the routed parts of the four shares, with the shared expert counted
-    once, give what the uncut reference gives for the whole layer."""
+    once, give what the uncut reference gives for the whole layer.  The
+    layer's parts are read from the session of an eager prefill, which
+    keeps every table of its plan (a compiled one returns the caches)."""
+    eager(monkeypatch)
     uncut = config(num_hidden_layers=1, first_k_dense_replace=0,
                    n_routed_experts=16, first_held_expert=0)
     params = weights(uncut, 5)
@@ -168,6 +172,53 @@ def test_no_token_is_dropped_when_all_route_to_the_same_experts(k):
     assert np.abs(got - want).max() < TOL
     load = eng.metrics.histogram("moe_expert_load_max", phase="prefill")
     assert load.count == 2 and load.sum == 2 * len(prompt)
+
+
+def series(eng):
+    """The prefill's routing series: pairs, then (count, sum) of the
+    touched and load histograms."""
+    m = eng.metrics
+    return (m.counter("moe_routed_pairs_total", phase="prefill").value,
+            *((h.count, h.sum) for h in (
+                m.histogram("moe_experts_touched", phase="prefill"),
+                m.histogram("moe_expert_load_max", phase="prefill"))))
+
+
+@pytest.mark.parametrize("T", [5, 16])
+def test_compiled_prefill_matches_eager_and_the_reference(T, monkeypatch):
+    """The cold prefill compiled for its bucket (5 tokens pad to 8, 16
+    fill 16) gives the eager prefill's logits, both within ``TOL`` of the
+    reference, and its routing series count what the eager prefill of
+    the prompt alone counts."""
+    cfg = config()
+    params = weights(cfg, 13)
+    prompt = [int(t) for t in np.random.default_rng(T).integers(0, 64, T)]
+    compiled = engine(cfg, params, metrics=MetricsRegistry())
+    got = compiled.start_session(prompt)["logits"]
+    assert set(compiled._prefill_ticks) == {(-(-T // 8) * 8, 1)}
+    eager(monkeypatch)
+    slow = engine(cfg, params, metrics=MetricsRegistry())
+    want = slow.start_session(prompt)["logits"]
+    assert not slow._prefill_ticks
+    np.testing.assert_allclose(got, want, rtol=0, atol=1e-5)
+    assert np.abs(got - ref_logits(cfg, params, prompt)[-1]).max() < TOL
+    assert series(compiled) == series(slow)
+    assert series(compiled)[0] > 0
+
+
+def test_the_series_count_the_real_rows_of_a_padded_bucket():
+    """Every token on experts 0 and 1 (top-2 of an all-equal router): a
+    5-token prompt in a bucket of 8 routes 2 pairs a real token in each
+    of the two expert layers, and loads each expert with the 5 real
+    tokens, not the 8 rows the program computed."""
+    cfg = config(first_held_expert=0, num_experts_per_tok=2)
+    eng = engine(cfg, zero_router(weights(cfg, 4)),
+                 metrics=MetricsRegistry())
+    eng.start_session([3, 17, 42, 5, 9])
+    assert set(eng._prefill_ticks) == {(8, 1)}
+    assert eng.metrics.counter("engine_prefill_padded_tokens_total").value \
+        == 3
+    assert series(eng) == (2 * 2 * 5, (2, 2 * 2), (2, 2 * 5))
 
 
 # -- the relations ---------------------------------------------------------

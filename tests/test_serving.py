@@ -836,3 +836,167 @@ class TestCompiledDecodeTick:
                        for tick, _ in eng._decode_ticks.values())
         else:
             assert not eng._decode_ticks
+
+
+class TestCompiledPrefill:
+    """An in-memory, untraced, unsharded engine runs each cold prefill as
+    one compiled program per prompt-length bucket (``T`` rounded up to the
+    chunk size, at most ``max_len``), the prompt padded at the end; suffix
+    prefills of a prefix hit and paged, traced and sharded engines
+    dispatch the plan eagerly.  Both give the same logits and cache rows
+    for the prompt's tokens."""
+
+    PREFIX = [5, 9, 2, 7, 11, 4, 6, 8]   # two full hash blocks (block=4)
+
+    @staticmethod
+    def _spec():
+        from repro.core.llama_graph import LlamaSpec
+        return LlamaSpec(vocab=64, d_model=32, n_layers=2, n_heads=4,
+                         n_kv=2, d_ff=64, rope_theta=10000.0)
+
+    @pytest.fixture(scope="class")
+    def params(self):
+        from repro.core.llama_graph import init_llama_params
+        return init_llama_params(self._spec(), seed=5)
+
+    def _engine(self, params, kind="in_memory", metrics=None, max_len=24):
+        from repro.obs import TraceRecorder
+        from repro.serving.engine import RelationalEngine
+        kw = {"paged": dict(residency="paged", budget_bytes=1 << 22),
+              "traced": dict(tracer=TraceRecorder()),
+              "sharded": dict(shards=2)}.get(kind, {})
+        return RelationalEngine(self._spec(), params, chunk_size=8,
+                                max_len=max_len, metrics=metrics, **kw)
+
+    @staticmethod
+    def _cache_rows(env, T):
+        """Each cache table's rows at positions ``< T``, positions first."""
+        out = {}
+        for name, t in env.items():
+            if name.startswith(("k_cache_L", "v_cache_L")):
+                [col] = t.cols.values()
+                ax = t.key_names.index("tp")
+                out[name] = np.moveaxis(np.asarray(col), ax, 0)[:T]
+        return out
+
+    @pytest.mark.parametrize("T", [8, 16, 5, 13])
+    def test_compiled_prefill_matches_eager(self, params, T):
+        """Last-row logits and the cache rows of the prompt's tokens, for
+        a prompt that fills its bucket (8, 16) and for one that pads."""
+        compiled = self._engine(params)
+        eager = self._engine(params, "traced")
+        assert compiled.runs_compiled and not eager.runs_compiled
+        prompt = [int(t) for t in np.random.default_rng(T).integers(0, 64, T)]
+        c, e = compiled.start_session(prompt), eager.start_session(prompt)
+        assert c["logits"].shape == e["logits"].shape == (64,)
+        np.testing.assert_allclose(c["logits"], e["logits"], rtol=0,
+                                   atol=1e-5)
+        assert c["tok"] == e["tok"] and c["pos"] == e["pos"] == T
+        want = self._cache_rows(e["env"], T)
+        got = self._cache_rows(c["env"], T)
+        assert set(got) == set(want) and len(got) == 4
+        for name in want:
+            np.testing.assert_allclose(got[name], want[name], rtol=0,
+                                       atol=1e-5)
+        assert set(compiled._prefill_ticks) == {
+            (compiled._prefill_bucket(T), 1)}
+        assert not eager._prefill_ticks
+
+    def _serve(self, eng):
+        """Prefills through ``prefill_ex`` (padded, whole-bucket and a
+        prefix hit), then decode ticks over changing batches; returns
+        every token served."""
+        dec = eng.batched_decoder(max_seqs=4, prefix_block=4)
+        prompts = [self.PREFIX + [1], [3, 4, 5], self.PREFIX + [2],
+                   [7, 1, 4, 2, 9, 3, 8, 6]]
+        tok, served = {}, []
+        for s, p in enumerate(prompts):
+            tok[s], cached = dec.prefill_ex(p, s)
+            assert cached == (len(self.PREFIX) if s == 2 else 0)
+            served.append(tok[s])
+        for ids in ([0, 1, 2, 3], [0, 1, 2, 3], [1, 3], [0, 2, 3],
+                    [0, 1, 2, 3]):
+            out = dec.decode(ids, [tok[s] for s in ids])
+            tok.update(zip(ids, out))
+            served += out
+        return served
+
+    def test_served_tokens_match_eager(self, params):
+        """``prefill_ex`` then decode ticks serve the same tokens on both
+        paths, a padded prompt and a suffix prefill over a compiled
+        prefill's cache tables among them."""
+        assert self._serve(self._engine(params)) == \
+            self._serve(self._engine(params, "traced"))
+
+    @pytest.mark.parametrize("max_len,T,bucket", [
+        (24, 1, 8), (24, 8, 8), (24, 9, 16), (24, 17, 24), (24, 24, 24),
+        (20, 13, 16), (20, 17, 20), (20, 20, 20)])
+    def test_bucket_rule(self, params, max_len, T, bucket):
+        """``T`` rounded up to the chunk size (8), at most ``max_len``."""
+        eng = self._engine(params, max_len=max_len)
+        assert eng._prefill_bucket(T) == bucket
+        prompt = list(range(1, T + 1))
+        np.testing.assert_allclose(
+            eng.start_session(prompt)["logits"],
+            self._engine(params, "traced", max_len=max_len).start_session(
+                prompt)["logits"], rtol=0, atol=1e-5)
+        assert set(eng._prefill_ticks) == {(bucket, 1)}
+
+    def test_one_program_per_bucket(self, params):
+        """Prompts of several lengths inside one bucket share one program;
+        a table a later plan adds to the weights leaves it as it is."""
+        eng = self._engine(params)
+        for T in (9, 12, 16, 10):
+            eng.start_session(list(range(3, 3 + T)))
+        eng.env_base["added_by_a_later_plan"] = eng.env_base["Final_Norm"]
+        eng.start_session(list(range(11)))
+        [(key, (prefill, _))] = eng._prefill_ticks.items()
+        assert key == (16, 1)
+        assert prefill._cache_size() == 1
+
+    @pytest.mark.parametrize("kind", ["paged", "traced", "sharded",
+                                      "suffix"])
+    def test_eager_paths(self, params, kind):
+        """Paged, traced and sharded engines prefill op by op, and so does
+        the suffix prefill of a prefix hit on an engine that compiles its
+        cold prefills."""
+        from repro.obs import MetricsRegistry
+        reg = MetricsRegistry()
+        eng = self._engine(params, "in_memory" if kind == "suffix" else kind,
+                           metrics=reg)
+        try:
+            dec = eng.batched_decoder(max_seqs=2, prefix_block=4)
+            dec.prefill_ex(self.PREFIX + [1], 0)
+            _, cached = dec.prefill_ex(self.PREFIX + [2], 1)
+        finally:
+            if eng.shard_pool is not None:
+                eng.shard_pool.shutdown()
+        assert cached == len(self.PREFIX)
+        counted = {e["labels"]["path"]: e["value"]
+                   for e in reg.to_dict()["engine_prefill_total"]}
+        if kind == "suffix":
+            assert counted == {"compiled": 1, "eager": 1}
+            assert set(eng._prefill_ticks) == {(16, 1)}
+        else:
+            assert counted == {"eager": 2}
+            assert not eng._prefill_ticks
+        assert reg.counter("engine_prefill_padded_tokens_total").value == \
+            (16 - 9 if kind == "suffix" else 0)
+
+    def test_series_count_a_hand_worked_sequence(self, params):
+        """Cold prefills of 3, 8, 10 and 17 tokens pad 5, 0, 6 and 7 rows;
+        ``generate`` prefills once more; a prefix hit runs eagerly."""
+        from repro.obs import MetricsRegistry
+        reg = MetricsRegistry()
+        eng = self._engine(params, metrics=reg)
+        for T in (3, 8, 10, 17):
+            eng.start_session(list(range(2, 2 + T)))
+        eng.generate([4, 4, 4], max_new_tokens=2)
+        dec = eng.batched_decoder(max_seqs=2, prefix_block=4)
+        dec.prefill_ex(self.PREFIX, 0)
+        dec.prefill_ex(self.PREFIX + [3, 3], 1)
+        counted = {e["labels"]["path"]: e["value"]
+                   for e in reg.to_dict()["engine_prefill_total"]}
+        assert counted == {"compiled": 6, "eager": 1}
+        assert reg.counter("engine_prefill_padded_tokens_total").value == \
+            5 + 0 + 6 + 7 + 5 + 0

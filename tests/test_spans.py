@@ -201,12 +201,12 @@ def test_served_spans_nest_on_the_profiler_clock(tmp_path):
     assert len(named("sched.decode")) == len(named("sched.emit")) == ticks
     assert ticks >= 2 and named("sched.wait")
     assert {ev[3]["rid"] for ev in named("sched.prefill")} == {2, 3}
-    # an in-memory engine runs each decode tick as one compiled program,
-    # whose steps annotate the host only while it is traced (the HLO names
-    # them, test_jitted_pipeline_names_each_step_in_its_hlo); prefill is
-    # dispatched step by step
-    assert engine.decode_path == "compiled"
-    n_steps = {"prefill": len(engine._prefill_pipe(3).steps), "decode": 0}
+    # an in-memory engine runs each decode tick and each cold prefill as
+    # one compiled program, whose steps annotate the host only while it is
+    # traced (the HLO names them,
+    # test_jitted_pipeline_names_each_step_in_its_hlo)
+    assert engine.runs_compiled
+    n_steps = {"prefill": 0, "decode": 0}
 
     def one_each(parent, names):
         kids = [_inside(sched, parent, n) for n in names]

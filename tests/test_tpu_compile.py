@@ -94,3 +94,39 @@ def test_grouped_matmul_compiles_at_expert_width(one_chip, rows):
     assert " while(" in text
     assert f"f32[{g},{rows},{n}]" not in text
     assert compiled.memory_analysis().temp_size_in_bytes < n * k * 4
+
+
+def test_prefill_program_takes_the_weights_as_parameters(one_chip):
+    """The tiny spec's cold prefill program for one length bucket compiles
+    for a described v5e as ``jit_prefill``, with every weight table an
+    argument: it holds no constant as large as the smallest table."""
+    import re
+
+    import numpy as np
+
+    from repro.core import llama_graph as lg
+    from repro.serving.engine import RelationalEngine
+    spec = lg.LlamaSpec(vocab=64, d_model=32, n_layers=2, n_heads=4, n_kv=2,
+                        d_ff=64, rope_theta=10000.0)
+    eng = RelationalEngine(spec, lg.init_llama_params(spec, seed=0),
+                           chunk_size=8, max_len=24)
+    bucket = eng._prefill_bucket(5)
+    prefill, names = eng._prefill_tick(bucket, eng._prefill_pipe(bucket))
+
+    def shape(x):
+        return jax.ShapeDtypeStruct(x.shape, x.dtype, sharding=one_chip)
+
+    weights = jax.tree.map(shape, {n: eng.env_base[n] for n in names})
+    inputs = jax.tree.map(shape, {
+        "token_ids": lg.token_table(np.zeros(bucket, np.int32)),
+        "freq_each_token": lg.rope_freq_table(np.arange(bucket),
+                                              spec.head_dim,
+                                              spec.rope_theta)})
+    text = prefill.lower(weights, inputs, jax.ShapeDtypeStruct(
+        (), jnp.int32, sharding=one_chip)).compile().as_text()
+    assert "jit_prefill" in text
+    smallest = min(x.size for x in jax.tree.leaves(weights))
+    constants = [int(np.prod([int(d) for d in dims.split(",") if d]))
+                 for dims in re.findall(r"f32\[([0-9,]*)\][^ ]* constant\(",
+                                        text)]
+    assert max(constants, default=0) < smallest
